@@ -67,16 +67,10 @@ def provenance() -> dict:
         ).stdout.strip() or None
     except (OSError, subprocess.SubprocessError):
         prov["git_sha"] = None
-    try:
-        import numpy
-        prov["numpy"] = numpy.__version__
-    except ImportError:
-        pass
-    try:
-        import jax
-        prov["jax"] = jax.__version__
-    except ImportError:
-        prov["jax"] = None
+    import jax
+    import numpy
+    prov["numpy"] = numpy.__version__
+    prov["jax"] = jax.__version__
     return prov
 
 
@@ -147,4 +141,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

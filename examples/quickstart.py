@@ -205,15 +205,11 @@ print("=== 8. Monte-Carlo resilience: 10^4 failure draws, one call ===")
 # each cell is one device call, not 10^4 event-loop runs.  (The full
 # 10^4-draw grid is `python benchmarks/fig4_resilience.py
 # --monte-carlo`; this demo keeps draws small.)
-from repro.core import devicesim
-if devicesim.device_available():
-    from benchmarks.fig4_resilience import monte_carlo
-    rows8, _ = monte_carlo(P=16, n_tasks=192, draws=500, cells=(1, 15))
-    for k, tech, d8, mean8, ci8, *_ in rows8:
-        print(f"   k={k:2d} {tech:5s} rho_res = {mean8:.3f} "
-              f"+- {ci8:.3f} (95% CI, {d8} draws)")
-else:                                   # pragma: no cover - jax baked in
-    print("   (jax unavailable -- skipped)")
+from benchmarks.fig4_resilience import monte_carlo
+rows8, _ = monte_carlo(P=16, n_tasks=192, draws=500, cells=(1, 15))
+for k, tech, d8, mean8, ci8, *_ in rows8:
+    print(f"   k={k:2d} {tech:5s} rho_res = {mean8:.3f} "
+          f"+- {ci8:.3f} (95% CI, {d8} draws)")
 
 print("=== 9. Flight recorder: trace a chaos run, open in Perfetto ===")
 # Aggregates say WHAT happened; the trace shows WHEN.  Turn on the

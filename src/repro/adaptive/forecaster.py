@@ -154,8 +154,6 @@ def _device_sweep(portfolio, times, base, alive_stats, scale, prewarm):
     oracle, never silently mis-simulates.
     """
     from repro.core import devicesim
-    if not devicesim.device_available():
-        return [], list(portfolio)
     lows, cands, rest = [], [], []
     for cand in portfolio:
         spec, tech = _build_candidate(times, base, alive_stats, scale,

@@ -8,7 +8,7 @@ tasks.  Two faces here:
     simulator, derived from the REAL escape counts of the assigned region
     (time proportional to iterations executed) — this reproduces the
     paper's variance structure instead of assuming a distribution;
-  * ``compute_tile()/compute_tasks()`` — the actual JAX/Pallas compute,
+  * ``compute_tile()/compute_tiles()`` — the actual JAX/Pallas compute,
     used by the runtime examples (rDLB re-executing real tiles after
     injected failures, asserting the final image is loss-less).
 """
@@ -65,14 +65,22 @@ def task_times(n_tasks: int = PAPER_N, *, side: int = SIDE,
 def compute_tile(tile_id: int, *, side: int = SIDE, tile: int = 64,
                  max_iters: int = MAX_ITERS) -> np.ndarray:
     """Compute one (tile x tile) tile — a runtime task. Deterministic."""
+    return compute_tiles(tile_id, tile_id + 1, side=side, tile=tile,
+                         max_iters=max_iters)[0]
+
+
+def compute_tiles(start: int, stop: int, *, side: int = SIDE,
+                  tile: int = 64, max_iters: int = MAX_ITERS) -> np.ndarray:
+    """Compute tiles ``[start, stop)`` (row-major tile ids) as ONE kernel
+    launch: the tiles are stacked into a (k*tile, tile) grid whose kernel
+    blocks are the tiles themselves.  Returns (k, tile, tile); each tile
+    equals ``compute_tile`` of its id."""
     per_row = side // tile
-    ty, tx = divmod(tile_id, per_row)
-    cr, ci = grid(side)
-    sl = (slice(ty * tile, (ty + 1) * tile),
-          slice(tx * tile, (tx + 1) * tile))
-    return np.asarray(mandelbrot_kernel(cr[sl], ci[sl],
-                                        max_iters=max_iters,
-                                        bm=tile, bn=tile))
+    cr, ci = (g.reshape(per_row, tile, per_row, tile).transpose(0, 2, 1, 3)
+              .reshape(per_row * per_row, tile, tile)[start:stop]
+              .reshape(-1, tile) for g in grid(side))
+    out = mandelbrot_kernel(cr, ci, max_iters=max_iters, bm=tile, bn=tile)
+    return np.asarray(out).reshape(-1, tile, tile)
 
 
 def n_tiles(side: int = SIDE, tile: int = 64) -> int:

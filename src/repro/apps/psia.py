@@ -20,6 +20,7 @@ from repro.kernels.ops import spin_image as spin_image_kernel
 PAPER_N = 20_000           # oriented points (tasks)
 CLOUD = 16_384             # cloud points binned per task
 N_ALPHA = N_BETA = 64
+BLOCK_P = 1024             # cloud points binned per kernel grid step
 
 
 @functools.lru_cache(maxsize=2)
@@ -29,6 +30,7 @@ def cloud(n: int = CLOUD, seed: int = 0):
     return pts
 
 
+@functools.lru_cache(maxsize=2)
 def oriented_points(n: int = PAPER_N, seed: int = 1):
     k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
     ctr = jax.random.normal(k1, (n, 3), jnp.float32) * 0.5
@@ -50,13 +52,20 @@ def task_times(n_tasks: int = PAPER_N, *, cloud_n: int = CLOUD,
     return base * (1.0 + jitter * rng.standard_normal(n_tasks)).clip(0.5)
 
 
+@functools.partial(jax.jit, static_argnames=("n_alpha", "n_beta"))
+def _spin_images(pts, ctr, nrm, ids, *, n_alpha: int, n_beta: int):
+    return spin_image_kernel(
+        pts, ctr[ids], nrm[ids], n_alpha=n_alpha, n_beta=n_beta,
+        alpha_max=3.0, beta_max=3.0, block_p=BLOCK_P)
+
+
 def compute_tasks(task_ids, *, n: int = PAPER_N, cloud_n: int = CLOUD,
                   n_alpha: int = N_ALPHA, n_beta: int = N_BETA
                   ) -> np.ndarray:
-    """Compute spin images for a chunk of oriented points (runtime tasks)."""
-    pts = cloud(cloud_n)
+    """Compute spin images for a chunk of oriented points (runtime tasks)
+    as one device program: gather and kernel compile once per chunk
+    size."""
     ctr, nrm = oriented_points(n)
-    ids = jnp.asarray(task_ids)
-    return np.asarray(spin_image_kernel(
-        pts, ctr[ids], nrm[ids], n_alpha=n_alpha, n_beta=n_beta,
-        alpha_max=3.0, beta_max=3.0, block_p=1024))
+    return np.asarray(_spin_images(cloud(cloud_n), ctr, nrm,
+                                   jnp.asarray(task_ids), n_alpha=n_alpha,
+                                   n_beta=n_beta))

@@ -94,12 +94,18 @@ def _child_env() -> dict:
     """Environment for fresh-interpreter children: they rebuild sys.path
     from PYTHONPATH, so the repro source root must be on it absolutely
     (the parent may have been launched with a relative PYTHONPATH from
-    another cwd)."""
+    another cwd).
+
+    Children compute on the CPU (``JAX_PLATFORMS=cpu``): a chip belongs to
+    one process, and on a TPU host the parent already holds it, so a
+    child that asked for the TPU would fail or hang.  Model-backed process
+    mode is therefore a CPU chaos path (real kills of real processes),
+    not a way to spread work over chips."""
     import repro
     # repro is a namespace package (no __init__.py): __file__ is None,
     # so resolve the source root through __path__ instead
     src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     parts = env.get("PYTHONPATH", "")
     if src not in parts.split(os.pathsep):
         env["PYTHONPATH"] = src + os.pathsep + parts if parts else src

@@ -9,6 +9,8 @@ master at pickle time).
 
 They declare ``start_method = "spawn"``: a forked child must never run
 XLA inherited mid-fork; a spawned interpreter initializes JAX cleanly.
+The children run JAX on the CPU (``master._child_env``): one process
+holds a chip, so this mode is a CPU chaos path, never a TPU path.
 
 Numerics parity: the child computes with the same model code, params
 and greedy decode as the in-process paths, so duplicates remain

@@ -24,8 +24,8 @@ and batches whole sweeps into one ``jit``-compiled ``vmap`` call:
     oldest-first rotating pointer, duplicate-slot leaks on dup-holder
     death, and the non-robust Fig.-1b hang (``t_par = inf``).
 
-Everything runs in float64 (``jax.experimental.enable_x64`` scoped to the
-device calls only, so the rest of the process keeps JAX's f32 default)
+Everything runs in float64 (``jax.enable_x64`` scoped to the device calls
+only, so the rest of the process keeps JAX's f32 default)
 and is vmapped over a leading (candidate × draw) axis.  Static scan
 budgets are computed host-side from the batch's worst case; an element
 that exhausts its budget comes back with ``valid=False`` and the caller
@@ -46,37 +46,13 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 _BIG = np.int32(2 ** 30)        # "no chunk" sentinel in seq space
 _NEVER = np.float64(np.inf)     # "never fails"
-
-# ----------------------------------------------------------------- jax gate
-_JAX = None
-
-
-def _jax():
-    global _JAX
-    if _JAX is None:
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax import lax
-            from jax.experimental import enable_x64
-            _JAX = (jax, jnp, lax, enable_x64)
-        except Exception as e:  # pragma: no cover - jax is baked in here
-            _JAX = e
-    if isinstance(_JAX, Exception):
-        raise RuntimeError(f"jax unavailable: {_JAX}")
-    return _JAX
-
-
-def device_available() -> bool:
-    try:
-        _jax()
-        return True
-    except RuntimeError:
-        return False
 
 
 # ---------------------------------------------------------------- lowering
@@ -202,7 +178,6 @@ def _round_phase(st, const, *, P, R_max, nofail=False):
     ``nofail`` (static) specializes for elements with no fail-stop draws
     (the clean tails' precondition): the piggyback gate, loss check and
     death bookkeeping vanish from the compiled scan step."""
-    _, jnp, lax, _ = _jax()
     cost_at, size_at, nc, fail, h, lat, speed = const
     widx = jnp.arange(P, dtype=jnp.int32)
 
@@ -259,7 +234,6 @@ def _round_b(st_b, const, *, P, r, M_B, orderB):
     """Round B: the first r-1 served remainder reports each trigger one
     more rDLB duplicate (queue not yet done) — an O(r) micro-loop walks
     the re-issue ring pointer exactly.  Shared by both clean tails."""
-    _, jnp, lax, _ = _jax()
     cost_at, size_at, nc, fail, h, lat, speed, rdlb = const
 
     def stepB(j, carry):
@@ -302,7 +276,6 @@ def _clean_tail(st, const, *, P):
     all round-A reports, and every duplicate report after all original
     reports — guaranteed for uniform full chunks, but a very cheap
     partial chunk against a large P*h master span can violate it."""
-    _, jnp, lax, _ = _jax()
     cost_at, size_at, nc, fail, h, lat, speed, rdlb = const
     (arrive, held, first, dead, nxt, mfree, nleft,
      tasks, busy, last_done, n_assign) = st
@@ -392,7 +365,6 @@ def _clean_tail_sorted(st, const, *, P):
     reuses the exact O(r) ring walk.
 
     Same phase-separation validity contract as :func:`_clean_tail`."""
-    _, jnp, lax, _ = _jax()
     cost_at, size_at, nc, fail, h, lat, speed, rdlb = const
     (arrive, held, first, dead, nxt, mfree, nleft,
      tasks, busy, last_done, n_assign) = st
@@ -457,7 +429,6 @@ def _txn_tail(st, const, *, P, T_max):
     master transaction) — commit / first-completion-wins / ring re-issue
     / duplicate-slot leak / retirement / Fig.-1b hang semantics exactly
     as ``Engine.run``."""
-    _, jnp, lax, _ = _jax()
     cost_at, size_at, nc, fail, h, lat, speed, rdlb = const
     widx = jnp.arange(P, dtype=jnp.int32)
     (arrive, held, first, dead, nxt, mfree, nleft,
@@ -567,7 +538,6 @@ _TAILS = ("sorted", "general", "txn")
 
 def _simulate_one(tech_ix, rdlb, fail, h, lat, speed, tables, *,
                   P, R_max, T_max, tail):
-    _, jnp, lax, _ = _jax()
     t_costs, t_sizes, t_nc, t_N = tables
     nc = t_nc[tech_ix]
     N = t_N[tech_ix]
@@ -614,7 +584,6 @@ def _compiled(P, C, R_max, T_max, tail):
     fn = _COMPILE_CACHE.get(key)
     if fn is not None:
         return fn
-    jax, jnp, _, _ = _jax()
 
     def batch(tech_ix, rdlb, fail, h, lat, speed, t_costs, t_sizes,
               t_nc, t_N):
@@ -648,6 +617,36 @@ def _bucket(n: int) -> int:
 
 
 # --------------------------------------------------------------- host API
+def batch_program(lowerings: Sequence[DeviceLowering], tech_of: np.ndarray,
+                  fail: np.ndarray, tail: str) -> tuple:
+    """``(fn, args)``: the jitted ``tail`` batch simulator for elements
+    ``tech_of`` (indices into ``lowerings``) with per-worker fail-stop
+    instants ``fail`` [B, P], and its host arguments — what
+    :func:`simulate_many` runs, exposed so a compile check can lower it
+    without running it."""
+    P = lowerings[0].P
+    U = len(lowerings)
+    C = max(lo.n_chunks for lo in lowerings)
+    t_costs = np.zeros((U, C))
+    t_sizes = np.zeros((U, C), dtype=np.int32)
+    t_nc = np.zeros(U, dtype=np.int32)
+    t_N = np.zeros(U, dtype=np.int64)
+    for u, lo in enumerate(lowerings):
+        t_costs[u, :lo.n_chunks] = lo.chunk_costs
+        t_sizes[u, :lo.n_chunks] = lo.chunk_sizes
+        t_nc[u] = lo.n_chunks
+        t_N[u] = lo.N
+    k_max = int(np.isfinite(fail).sum(axis=1).max(initial=0))
+    surv = max(1, P - k_max)
+    R_max = _bucket(int(-(-int(t_nc[tech_of].max()) // surv)) + 2)
+    T_max = _bucket(4 * P + 16 * k_max + 64) if tail == "txn" else 0
+    of = lambda field: np.array([getattr(lowerings[u], field)
+                                 for u in tech_of])
+    return (_compiled(P, C, R_max, T_max, tail),
+            (tech_of, of("rdlb"), fail, of("h"), of("lat"), of("speed"),
+             t_costs, t_sizes, t_nc, t_N))
+
+
 def simulate_many(lowerings: Sequence[DeviceLowering],
                   tech_of: Optional[np.ndarray] = None,
                   fail_times: Optional[np.ndarray] = None
@@ -663,15 +662,13 @@ def simulate_many(lowerings: Sequence[DeviceLowering],
     combined (min) with each lowering's own spec-declared instants.
     Defaults: one element per lowering, no extra draws.
     """
-    jax, jnp, _, enable_x64 = _jax()
     if not lowerings:
         raise ValueError("need at least one lowering")
     P = lowerings[0].P
     if any(lo.P != P for lo in lowerings):
         raise ValueError("all lowerings in a batch must share P")
-    U = len(lowerings)
     if tech_of is None:
-        tech_of = np.arange(U, dtype=np.int32)
+        tech_of = np.arange(len(lowerings), dtype=np.int32)
     tech_of = np.asarray(tech_of, dtype=np.int32)
     B = len(tech_of)
     spec_fail = np.stack([lo.fail_time for lo in lowerings])[tech_of]
@@ -680,24 +677,10 @@ def simulate_many(lowerings: Sequence[DeviceLowering],
     else:
         fail = np.minimum(np.asarray(fail_times, dtype=np.float64),
                           spec_fail)
-    C = max(lo.n_chunks for lo in lowerings)
-    t_costs = np.zeros((U, C))
-    t_sizes = np.zeros((U, C), dtype=np.int32)
-    t_nc = np.zeros(U, dtype=np.int32)
-    t_N = np.zeros(U, dtype=np.int64)
-    for u, lo in enumerate(lowerings):
-        t_costs[u, :lo.n_chunks] = lo.chunk_costs
-        t_sizes[u, :lo.n_chunks] = lo.chunk_sizes
-        t_nc[u] = lo.n_chunks
-        t_N[u] = lo.N
-    h = np.array([lowerings[u].h for u in tech_of])
-    lat = np.array([lowerings[u].lat for u in tech_of])
-    speed = np.array([lowerings[u].speed for u in tech_of])
-    rdlb = np.array([lowerings[u].rdlb for u in tech_of])
-    nc_of = t_nc[tech_of]
+    nc_of = np.array([lo.n_chunks for lo in lowerings])[tech_of]
+    t_N = np.array([lo.N for lo in lowerings], dtype=np.int64)
 
-    k_of = np.isfinite(fail).sum(axis=1)
-    clean_mask = (k_of == 0) & (nc_of >= P)
+    clean_mask = (np.isfinite(fail).sum(axis=1) == 0) & (nc_of >= P)
     # serve order == index order unless P | nc AND the last chunk is
     # partial (then the cheap partial chunk is in flight during the tail's
     # round A and reports early) — those take the O(P) ring-walk tail
@@ -716,24 +699,14 @@ def simulate_many(lowerings: Sequence[DeviceLowering],
         "tasks_done": np.zeros((B, P), np.int64),
         "last_done": np.zeros((B, P)),
     }
-    alive = np.ones((B, P), bool)
 
     def run_sub(idx: np.ndarray, tail: str) -> None:
         if len(idx) == 0:
             return
-        sub_nc = nc_of[idx]
-        k_max = int(k_of[idx].max(initial=0))
-        surv = max(1, P - k_max)
-        R_max = _bucket(int(-(-int(sub_nc.max()) // surv)) + 2)
-        T_max = _bucket(4 * P + 16 * k_max + 64) if tail == "txn" else 0
-        fn = _compiled(P, C, R_max, T_max, tail)
-        res = fn(jnp.asarray(tech_of[idx]), jnp.asarray(rdlb[idx]),
-                 jnp.asarray(fail[idx]), jnp.asarray(h[idx]),
-                 jnp.asarray(lat[idx]), jnp.asarray(speed[idx]),
-                 jnp.asarray(t_costs), jnp.asarray(t_sizes),
-                 jnp.asarray(t_nc), jnp.asarray(t_N))
+        fn, args = batch_program(lowerings, tech_of[idx], fail[idx], tail)
+        res = fn(*(jnp.asarray(a) for a in args))
         (t_par, hung, valid, nleft, n_assign, n_dups, wasted,
-         tasks, busy, last_done, alv) = (np.asarray(x) for x in res)
+         tasks, busy, last_done, _) = (np.asarray(x) for x in res)
         out["t_par"][idx] = t_par
         out["hung"][idx] = hung
         out["valid"][idx] = valid
@@ -744,9 +717,8 @@ def simulate_many(lowerings: Sequence[DeviceLowering],
         out["pe_busy"][idx] = busy
         out["tasks_done"][idx] = tasks
         out["last_done"][idx] = last_done
-        alive[idx] = alv
 
-    with enable_x64():
+    with jax.enable_x64(True):
         run_sub(np.flatnonzero(sorted_mask), "sorted")
         run_sub(np.flatnonzero(clean_mask & ~sorted_mask), "general")
         run_sub(np.flatnonzero(~clean_mask), "txn")

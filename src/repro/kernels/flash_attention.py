@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels.dispatch import pallas_call
+
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -82,77 +84,77 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_s, l_s, acc_s,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    q = q_ref[...].astype(jnp.float32)                 # (1, d)
+    q = q_ref[0].astype(jnp.float32)                   # (1, d)
     k = k_ref[0].astype(jnp.float32)                   # (bk, d)
     v = v_ref[0].astype(jnp.float32)                   # (bk, dv)
     ok = valid_ref[...] != 0                           # (1, bk)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = jnp.where(ok, s, NEG_INF)                      # (1, bk)
-    m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))        # (1,)
+    m_prev = m_s[...]                                  # (1, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     # a fully-masked block leaves m_new at NEG_INF; exp(s - m_new) would
     # be exp(0)=1 there, so re-zero masked probabilities explicitly
-    p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * corr + p.sum(axis=-1)
-    acc_s[...] = (acc_s[...] * corr[:, None]
+    l_s[...] = l_s[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_s[...] = (acc_s[...] * corr
                   + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32))
     m_s[...] = m_new
 
     @pl.when(kj == pl.num_programs(1) - 1)
     def _():
-        out = acc_s[...] / jnp.maximum(l_s[...], 1e-30)[:, None]
-        o_ref[...] = out.astype(o_ref.dtype)
+        out = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "bk"))
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                  valid: jax.Array, *, scale: float | None = None,
-                 bk: int = 128, interpret: bool = True) -> jax.Array:
+                 bk: int = 128) -> jax.Array:
     """Decode-variant flash attention: q (B, D) single-token queries vs a
     KV cache k/v (B, L, D|Dv) with a shared (L,) validity mask (int/bool;
-    nonzero = slot participates).  Returns (B, Dv)."""
+    nonzero = slot participates).  Returns (B, Dv).  Each query row is a
+    (1, 1, D) block of a (B, 1, D) view, which meets the TPU's tiling
+    where a (1, D) block of (B, D) does not."""
     B, D = q.shape
     L, Dv = k.shape[1], v.shape[-1]
     bk = min(bk, L)
     assert L % bk == 0, (L, bk)
     scale = scale if scale is not None else D ** -0.5
     valid2 = valid.astype(jnp.int32)[None, :]           # (1, L)
-    return pl.pallas_call(
+    out = pallas_call(
         functools.partial(_decode_kernel, scale=scale),
         grid=(B, L // bk),
         in_specs=[
-            pl.BlockSpec((1, D), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, 1, D), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, bk, Dv), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, bk), lambda b, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, Dv), lambda b, j: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, Dv), lambda b, j: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, Dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, Dv), jnp.float32),
         ],
-        interpret=interpret,
-    )(q, k, v, valid2)
+    )(q[:, None, :], k, v, valid2)
+    return out[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "bq", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "bq", "bk"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: float | None = None,
-                    bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    bq: int = 128, bk: int = 128) -> jax.Array:
     """q,k,v: (B, S, D) per-head layout -> (B, S, Dv)."""
     B, S, D = q.shape
     Dv = v.shape[-1]
     bq, bk = min(bq, S), min(bk, S)
     assert S % bq == 0 and S % bk == 0, (S, bq, bk)
     scale = scale if scale is not None else D ** -0.5
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_kernel, scale=scale, causal=causal, bq=bq,
                           bk=bk),
         grid=(B, S // bq, S // bk),
@@ -168,5 +170,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
-        interpret=interpret,
     )(q, k, v)

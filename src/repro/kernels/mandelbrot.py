@@ -10,7 +10,9 @@ escape loop is a fori_loop over fused VPU ops on the whole (bm, bn) tile.
 Escaped lanes are frozen (masked select) — no divergence penalty on the
 VPU, and no NaN pollution from diverged z values.  Tile 256x256 f32 ~
 256 KB/operand in VMEM: far under the 16 MB budget, big enough to amortize
-grid overhead.
+grid overhead.  The int32 escape counter starts from the input tile, not
+from a constant: Mosaic refuses to relayout a loop-carried vector that
+starts replicated (a splat) and leaves the loop unreplicated.
 """
 
 from __future__ import annotations
@@ -21,13 +23,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import pallas_call
+
 
 def _kernel(cr_ref, ci_ref, out_ref, *, max_iters: int):
     cr = cr_ref[...]
     ci = ci_ref[...]
     zr = jnp.zeros_like(cr)
     zi = jnp.zeros_like(ci)
-    cnt = jnp.zeros(cr.shape, jnp.int32)
+    cnt = (cr * 0.0).astype(jnp.int32)      # not a splat: see docstring
 
     def body(_, st):
         zr, zi, cnt = st
@@ -44,21 +48,19 @@ def _kernel(cr_ref, ci_ref, out_ref, *, max_iters: int):
     out_ref[...] = cnt
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("max_iters", "bm", "bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("max_iters", "bm", "bn"))
 def mandelbrot(c_real: jax.Array, c_imag: jax.Array, *,
-               max_iters: int = 256, bm: int = 256, bn: int = 256,
-               interpret: bool = True) -> jax.Array:
+               max_iters: int = 256, bm: int = 256,
+               bn: int = 256) -> jax.Array:
     """Escape counts for a (M, N) grid of complex c values."""
     M, N = c_real.shape
     bm, bn = min(bm, M), min(bn, N)
     assert M % bm == 0 and N % bn == 0, (M, N, bm, bn)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_kernel, max_iters=max_iters),
         grid=(M // bm, N // bn),
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
                   pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
-        interpret=interpret,
     )(c_real, c_imag)

@@ -1,7 +1,7 @@
 """Public jitted wrappers for the Pallas kernels (the API the rest of the
-framework calls).  ``interpret=True`` by default: kernel bodies execute on
-this CPU container; on TPU pass interpret=False (same BlockSpecs compile
-to Mosaic)."""
+framework calls).  Each kernel compiles with Mosaic when lowered for the
+TPU and runs in the Pallas interpreter elsewhere
+(``repro.kernels.dispatch.pallas_call``)."""
 
 from __future__ import annotations
 
@@ -17,10 +17,9 @@ from repro.kernels.spin_image import spin_image            # noqa: F401
 
 
 def mha_flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
-              causal: bool = True, interpret: bool = True) -> jax.Array:
+              causal: bool = True) -> jax.Array:
     """Multi-head convenience: q,k,v (B, S, H, D) -> (B, S, H, Dv)."""
     B, S, H, D = q.shape
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, S, t.shape[-1])
-    out = flash_attention(fold(q), fold(k), fold(v), causal=causal,
-                          interpret=interpret)
+    out = flash_attention(fold(q), fold(k), fold(v), causal=causal)
     return out.reshape(B, H, S, -1).transpose(0, 2, 1, 3)

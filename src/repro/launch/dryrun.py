@@ -76,8 +76,6 @@ def lower_cell(arch: str, shape_name: str, mesh, *, mesh_name: str,
 
     chips = mesh_lib.mesh_chips(mesh)
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # older jax: one dict per program
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_info = {

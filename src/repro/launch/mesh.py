@@ -22,11 +22,8 @@ ICI_BW = 50e9                  # B/s per link
 
 
 def _mesh(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):    # newer jax: explicit Auto
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)        # older jax: Auto is implied
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

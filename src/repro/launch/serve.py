@@ -16,6 +16,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke
 from repro.models import build_model
 from repro.runtime import RDLBServeExecutor, Request
@@ -66,4 +67,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

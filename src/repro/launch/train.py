@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke
 from repro.data import batch_for_step
 from repro.models import build_model
@@ -138,4 +139,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

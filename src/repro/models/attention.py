@@ -248,8 +248,8 @@ def gqa_decode(p, cfg: ModelConfig, x, cache: dict, pos: jax.Array, *,
     With ``cfg.use_kernel`` the cache attention runs through the Pallas
     ``flash_decode`` kernel (q_len=1 online softmax over kv-cache blocks,
     the per-slot validity mask standing in for the causal structure); the
-    jnp ``_attend`` path below is its parity oracle.  Kernel failures fall
-    back to jnp, recorded via repro.kernels.dispatch (never silent)."""
+    jnp ``_attend`` path below is its parity oracle.  The path taken is
+    recorded in repro.kernels.dispatch; a kernel error propagates."""
     B = x.shape[0]
     dh, h, kvh = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     g = h // kvh
@@ -276,22 +276,15 @@ def gqa_decode(p, cfg: ModelConfig, x, cache: dict, pos: jax.Array, *,
         mask = valid[None, None, None, :]
     if (getattr(cfg, "use_kernel", False) and valid is not None
             and k.shape[1] % min(128, k.shape[1]) == 0):
-        try:
-            from repro.kernels import dispatch
-            from repro.kernels.flash_attention import flash_decode
-            L = k.shape[1]
-            kf = _repeat_kv(k, g).transpose(0, 2, 1, 3).reshape(B * h, L, dh)
-            vf = _repeat_kv(v, g).transpose(0, 2, 1, 3).reshape(B * h, L, dh)
-            qf = q.reshape(B * h, dh)
-            out = flash_decode(qf, kf, vf, valid, scale=dh ** -0.5,
-                               bk=min(128, L))
-            out = out.reshape(B, 1, h * dh)
-            dispatch.record("gqa_decode", "pallas")
-            return dense(p["o"], out), new_cache
-        except Exception as e:  # pragma: no cover - exercised via tests
-            from repro.kernels import dispatch
-            dispatch.record("gqa_decode", "jnp-fallback",
-                            reason=f"{type(e).__name__}: {e}")
+        from repro.kernels import dispatch
+        from repro.kernels.flash_attention import flash_decode
+        L = k.shape[1]
+        kf = _repeat_kv(k, g).transpose(0, 2, 1, 3).reshape(B * h, L, dh)
+        vf = _repeat_kv(v, g).transpose(0, 2, 1, 3).reshape(B * h, L, dh)
+        out = flash_decode(q.reshape(B * h, dh), kf, vf, valid,
+                           scale=dh ** -0.5, bk=min(128, L))
+        dispatch.record("gqa_decode", "pallas")
+        return dense(p["o"], out.reshape(B, 1, h * dh)), new_cache
     q = constrain(q, ("batch", "seq", "heads", None))
     k = constrain(_repeat_kv(k, g), ("batch", "cache_seq", "heads", None))
     v = constrain(_repeat_kv(v, g), ("batch", "cache_seq", "heads", None))

@@ -76,8 +76,9 @@ class ModelConfig:
 
     # --- performance knobs (§Perf hillclimb; defaults = baseline) ---
     use_kernel: bool = False       # route decode/prefill through the Pallas
-                                   # kernels (repro.kernels; interpret mode
-                                   # on CPU) instead of the jnp twins
+                                   # kernels (repro.kernels; Mosaic on the
+                                   # TPU, interpreter elsewhere) instead of
+                                   # the jnp twins
     flash_threshold: int = 8192    # min seq len for chunked online-softmax
     flash_causal_skip: bool = False  # triangle schedule (skip future chunks)
     attn_scores_bf16: bool = False   # bf16 S^2 tensors (halved traffic;

@@ -166,40 +166,30 @@ def time_mix(p, cfg: ModelConfig, x, prev_tok, wkv_state, *,
     rh, kh, vh, wh = split(r), split(k), split(v), split(w)
     uh = p["u"].reshape(H, dh)
 
-    y = new_state = None
     if use_kernel:
         # Batched-heads Pallas dispatch: fold (B, H) into one grid axis so
         # the whole layer is a single pallas_call (prefill) or the fused
-        # single-step kernel (decode) — no vmapped per-head launches.  Any
-        # kernel failure falls back to the jnp twins below, logged once
-        # per process via repro.kernels.dispatch (never silently).
-        try:
-            from repro.kernels import dispatch, rwkv6_scan
-            BH = B * H
-            fold = lambda t: t.reshape(BH, S, dh)
-            uu = jnp.broadcast_to(uh[None], (B, H, dh)).reshape(BH, dh)
-            ss = wkv_state.reshape(BH, dh, dh).astype(jnp.float32)
-            if S == 1:
-                yk, sk = rwkv6_scan.wkv6_decode(
-                    fold(rh)[:, 0], fold(kh)[:, 0], fold(vh)[:, 0],
-                    fold(wh)[:, 0], uu, ss)
-                yk = yk[:, None, :]
-            else:
-                c = min(32, S)
-                while S % c:
-                    c -= 1
-                yk, sk = rwkv6_scan.wkv6_batched(
-                    fold(rh), fold(kh), fold(vh), fold(wh), uu, ss, chunk=c)
-            y = yk.reshape(B, H, S, dh).astype(x.dtype)
-            new_state = sk.reshape(B, H, dh, dh)
-            dispatch.record("wkv6", "pallas")
-        except Exception as e:  # pragma: no cover - exercised via tests
-            from repro.kernels import dispatch
-            dispatch.record("wkv6", "jnp-fallback",
-                            reason=f"{type(e).__name__}: {e}")
-            y = new_state = None
-
-    if y is None:
+        # single-step kernel (decode) — no vmapped per-head launches.
+        from repro.kernels import dispatch, rwkv6_scan
+        BH = B * H
+        fold = lambda t: t.reshape(BH, S, dh)
+        uu = jnp.broadcast_to(uh[None], (B, H, dh)).reshape(BH, dh)
+        ss = wkv_state.reshape(BH, dh, dh).astype(jnp.float32)
+        if S == 1:
+            yk, sk = rwkv6_scan.wkv6_decode(
+                fold(rh)[:, 0], fold(kh)[:, 0], fold(vh)[:, 0],
+                fold(wh)[:, 0], uu, ss)
+            yk = yk[:, None, :]
+        else:
+            c = min(32, S)
+            while S % c:
+                c -= 1
+            yk, sk = rwkv6_scan.wkv6_batched(
+                fold(rh), fold(kh), fold(vh), fold(wh), uu, ss, chunk=c)
+        y = yk.reshape(B, H, S, dh).astype(x.dtype)
+        new_state = sk.reshape(B, H, dh, dh)
+        dispatch.record("wkv6", "pallas")
+    else:
         def per_head(r, k, v, w, u, s):
             if S == 1:
                 return wkv6_sequential(r, k, v, w, u, s)

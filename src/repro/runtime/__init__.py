@@ -1,5 +1,5 @@
 from repro.runtime.backends import (  # noqa: F401
-    FnBackend, ServeBackend, TrainBackend,
+    ChunkBackend, FnBackend, ServeBackend, TrainBackend,
 )
 from repro.runtime.executor import (  # noqa: F401
     FaultPlan, RDLBTrainExecutor, StepResult, WorkerState,

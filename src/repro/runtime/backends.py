@@ -6,6 +6,8 @@ defines what a chunk of tasks IS:
 
   * :class:`FnBackend`      — run a Python callable per task (parity tests,
                               run_to_completion-style draining of real work);
+  * :class:`ChunkBackend`   — run a whole chunk as ONE call (the paper's
+                              loops: one kernel launch per chunk);
   * :class:`TrainBackend`   — grad-accumulation microbatches with
                               exactly-once-by-task-id reduction;
   * :class:`ServeBackend`   — inference requests, decoded per-request or as
@@ -58,6 +60,33 @@ class FnBackend(WorkerBackend):
             return
         for t in newly:
             self.results[t] = payload[t]
+
+
+class ChunkBackend(WorkerBackend):
+    """Execute a chunk as ONE call ``chunk_fn(start, stop)``, which
+    returns an array with one row per task of ``[start, stop)`` — the
+    paper's loops, whose task bodies are one kernel launch per chunk.
+
+    ``commit`` writes the rows a report newly finished into ``results``
+    (``n_tasks`` rows), exactly once by task id: a duplicate's rows land
+    only for tasks it won."""
+
+    def __init__(self, chunk_fn: Callable[[int, int], Any],
+                 n_tasks: int) -> None:
+        self.chunk_fn = chunk_fn
+        self.n_tasks = n_tasks
+        self.results: Optional[np.ndarray] = None
+
+    def execute(self, chunk: Chunk, wid: int) -> Any:
+        return np.asarray(self.chunk_fn(chunk.start, chunk.stop))
+
+    def commit(self, chunk: Chunk, wid: int, payload: Any,
+               newly: list[int]) -> None:
+        if self.results is None:
+            self.results = np.zeros((self.n_tasks,) + payload.shape[1:],
+                                    payload.dtype)
+        rows = np.asarray(newly, dtype=np.int64)
+        self.results[rows] = payload[rows - chunk.start]
 
 
 class TrainBackend(WorkerBackend):

@@ -1,9 +1,16 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests must see 1 CPU device
 (only launch/dryrun.py forces 512 placeholder devices, in its own process).
+
+The suite runs on the CPU, even on a machine with a chip: a chip belongs
+to one process, and the suite never takes it.
 """
 
-import jax
-import pytest
+import os
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import jax  # noqa: E402
+import pytest  # noqa: E402
 
 
 @pytest.fixture(scope="session")

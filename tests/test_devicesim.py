@@ -23,9 +23,6 @@ from repro.adaptive import capture, sweep
 from repro.api import DEVICE_PORTFOLIO
 from repro.core import devicesim, faults
 
-jax_missing = not devicesim.device_available()
-needs_jax = pytest.mark.skipif(jax_missing, reason="jax not installed")
-
 ATOL = 1e-9
 
 
@@ -64,7 +61,6 @@ def _check(spec, times, fail_times=None):
 
 
 # ------------------------------------------------------------- parity grid
-@needs_jax
 @pytest.mark.parametrize("tech", ["SS", "STATIC", "mFSC", "FSC"])
 @pytest.mark.parametrize("P", [4, 16, 64])
 def test_parity_clean_grid(tech, P):
@@ -76,7 +72,6 @@ def test_parity_clean_grid(tech, P):
             _check(_spec(tech, P, rdlb=rdlb), times)
 
 
-@needs_jax
 @pytest.mark.parametrize("tech", ["SS", "mFSC"])
 @pytest.mark.parametrize("k", [1, 2, None])      # None -> P-1
 def test_parity_failure_draws(tech, k):
@@ -96,7 +91,6 @@ def test_parity_failure_draws(tech, k):
     assert res.valid.all() and res.hung.all() and np.isinf(res.t_par[0])
 
 
-@needs_jax
 def test_parity_latency_and_small_N():
     """Message latency and N < P (transaction tail from the start)."""
     for tech, P, N in (("SS", 8, 5), ("STATIC", 8, 5), ("SS", 16, 300)):
@@ -109,7 +103,6 @@ def test_parity_latency_and_small_N():
         _check(spec, np.full(N, 0.01))
 
 
-@needs_jax
 def test_parity_monte_carlo_batch():
     """A batched MC cell (paired draws over 3 techniques) matches a
     per-draw scalar loop element-for-element."""
@@ -141,7 +134,6 @@ def test_parity_monte_carlo_batch():
 
 
 # --------------------------------------------------------- regime boundary
-@needs_jax
 def test_declines_never_missimulates():
     """Everything outside the homogeneous fixed-chunk regime must DECLINE
     at lowering — falling back to the scalar engine, not mis-simulating."""
@@ -173,7 +165,6 @@ def test_declines_never_missimulates():
     assert all(declined.values())
 
 
-@needs_jax
 def test_budget_exhaustion_flags_invalid():
     """An element that outruns its scan budget returns valid=False (the
     caller's cue to re-run on the scalar engine) — force it by calling
@@ -181,10 +172,10 @@ def test_budget_exhaustion_flags_invalid():
     times = np.full(400, 0.01)
     spec = _spec("SS", 4)
     lo, _ = devicesim.lower_run(spec, times)
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     fn = devicesim._compiled(4, lo.n_chunks, 16, 0, "sorted")
-    with enable_x64():
+    with jax.enable_x64(True):
         res = fn(jnp.zeros(1, jnp.int32), jnp.ones(1, bool),
                  jnp.full((1, 4), jnp.inf), jnp.full(1, lo.h),
                  jnp.full(1, lo.lat), jnp.full(1, lo.speed),
@@ -196,7 +187,6 @@ def test_budget_exhaustion_flags_invalid():
 
 
 # ------------------------------------------------------ forecaster parity
-@needs_jax
 def test_device_sweep_matches_scalar_sweep():
     """The batched portfolio forecast ranks and scores candidates exactly
     as the scalar per-candidate loop (t=0 snapshot, live engine)."""
@@ -216,7 +206,6 @@ def test_device_sweep_matches_scalar_sweep():
         assert a == pytest.approx(b, abs=ATOL)
 
 
-@needs_jax
 def test_adaptive_run_device_flag_is_transparent():
     """An end-to-end adaptive run makes identical decisions with
     device_sweep on and off (the flag changes cost, not behaviour)."""
@@ -251,7 +240,6 @@ def test_adaptivespec_device_flag_round_trips():
     assert again.adaptive.to_config().device_sweep is True
 
 
-@needs_jax
 def test_monte_carlo_smoke():
     """A tiny --monte-carlo cell produces finite rho with paired draws
     and the most robust technique pinned at 1.0."""

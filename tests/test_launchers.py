@@ -56,11 +56,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 import jax
 from repro.configs import get_smoke, input_specs, Shape
 from repro.launch.steps import make_train_step, make_serve_step
-if hasattr(jax.sharding, "AxisType"):
-    mesh = jax.make_mesh((4, 4), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-else:
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_smoke("qwen3-4b")
 with mesh:
     ts = make_train_step(cfg, mesh, num_microbatches=2)
@@ -70,8 +67,6 @@ with mesh:
     oa = jax.eval_shape(ts.opt.init, pa)
     c = ts.jit(specs, donate=False).lower(pa, oa, specs).compile()
     ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per program
-        ca = ca[0]
     assert ca["flops"] > 0
     ss = make_serve_step(cfg, mesh)
     sd = input_specs(cfg, Shape("d", 64, 16, "decode"), ss.model)

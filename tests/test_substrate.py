@@ -163,11 +163,8 @@ def test_rules_resolution():
 
 
 def test_rules_divisibility_fallback():
-    if hasattr(jax.sharding, "AxisType"):     # newer jax
-        mesh = jax.make_mesh((1, 1), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    else:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = AxisRules(make_rules())
     # 7 not divisible by model size 1? size-1 axes always divide: kept
     spec = rules.spec(("heads",), (7,), mesh)
