@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.ops import mandelbrot as mandelbrot_kernel
+from repro.runtime.backends import chunk_to_host
 
 REGION = (-2.0, 0.6, -1.3, 1.3)        # the classic view
 PAPER_N = 262_144                      # 512 x 512
@@ -76,11 +77,15 @@ def compute_tiles(start: int, stop: int, *, side: int = SIDE,
     blocks are the tiles themselves.  Returns (k, tile, tile); each tile
     equals ``compute_tile`` of its id."""
     per_row = side // tile
-    cr, ci = (g.reshape(per_row, tile, per_row, tile).transpose(0, 2, 1, 3)
-              .reshape(per_row * per_row, tile, tile)[start:stop]
-              .reshape(-1, tile) for g in grid(side))
-    out = mandelbrot_kernel(cr, ci, max_iters=max_iters, bm=tile, bn=tile)
-    return np.asarray(out).reshape(-1, tile, tile)
+
+    def dispatch():
+        cr, ci = (g.reshape(per_row, tile, per_row, tile)
+                  .transpose(0, 2, 1, 3)
+                  .reshape(per_row * per_row, tile, tile)[start:stop]
+                  .reshape(-1, tile) for g in grid(side))
+        return mandelbrot_kernel(cr, ci, max_iters=max_iters, bm=tile,
+                                 bn=tile)
+    return chunk_to_host(dispatch).reshape(-1, tile, tile)
 
 
 def n_tiles(side: int = SIDE, tile: int = 64) -> int:

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.ops import spin_image as spin_image_kernel
+from repro.runtime.backends import chunk_to_host
 
 PAPER_N = 20_000           # oriented points (tasks)
 CLOUD = 16_384             # cloud points binned per task
@@ -65,7 +66,8 @@ def compute_tasks(task_ids, *, n: int = PAPER_N, cloud_n: int = CLOUD,
     """Compute spin images for a chunk of oriented points (runtime tasks)
     as one device program: gather and kernel compile once per chunk
     size."""
-    ctr, nrm = oriented_points(n)
-    return np.asarray(_spin_images(cloud(cloud_n), ctr, nrm,
-                                   jnp.asarray(task_ids), n_alpha=n_alpha,
-                                   n_beta=n_beta))
+    def dispatch():
+        ctr, nrm = oriented_points(n)
+        return _spin_images(cloud(cloud_n), ctr, nrm, jnp.asarray(task_ids),
+                            n_alpha=n_alpha, n_beta=n_beta)
+    return chunk_to_host(dispatch)

@@ -530,7 +530,16 @@ class Engine:
         it) is interpreted as WALL seconds from run start: the worker
         thread fail-stops at that instant — mid-chunk it dies holding
         the chunk (never reports), exactly like a killed process.
+
+        The run is the program span ``engine.run``, opened at the
+        recorder's ``t0`` (``core/trace.py`` names every span).
         """
+        with trc.span("engine.run"):
+            return self._run_threaded(poll, stall_timeout)
+
+    def _run_threaded(self, poll: float, stall_timeout: float
+                      ) -> EngineStats:
+        t0 = time.monotonic()
         queue = self.queue
         # The count-based bound must never undercut the wall-clock one
         # for default knobs: only an explicit ExecutionSpec override
@@ -538,7 +547,6 @@ class Engine:
         max_polls = (self.max_fruitless_polls if self._fruitless_explicit
                      else math.inf)
         tr = self.trace
-        t0 = time.monotonic()
         errors: list[BaseException] = []
         if self.adaptive is not None:
             self.adaptive.bind(self)       # may re-plan before threads run
@@ -566,12 +574,10 @@ class Engine:
                         tr.event(trc.EV_DEATH, time.monotonic() - t0,
                                  w.wid, detail="fail_time")
                     return
-                if tr is None:
+                rq0 = time.monotonic()
+                with trc.span("engine.request"):
                     chunk = queue.request(w.wid)
-                else:
-                    _rq0 = time.monotonic()
-                    chunk = queue.request(w.wid)
-                    _rq_lat = time.monotonic() - _rq0
+                rq_lat = time.monotonic() - rq0
                 if chunk is None:
                     if queue.done:
                         return
@@ -605,7 +611,7 @@ class Engine:
                              else trc.EV_ASSIGN,
                              time.monotonic() - t0, w.wid, chunk.seq,
                              chunk.start, chunk.size,
-                             aux=chunk.origin_seq, dt=_rq_lat)
+                             aux=chunk.origin_seq, dt=rq_lat)
                 if w.fails_by_count():
                     if tr is not None:
                         tr.event(trc.EV_DEATH, time.monotonic() - t0,
@@ -631,22 +637,29 @@ class Engine:
                 dt_exec = time.monotonic() - t_exec0
                 w.busy += dt_exec
                 w.last_done = time.monotonic() - t0
-                with self._commit_lock:
-                    w.tasks_done += chunk.size
-                    self.by_worker[w.wid] = (self.by_worker.get(w.wid, 0)
-                                             + chunk.size)
-                    newly = queue.report_tasks(chunk)
-                    self.backend.commit(chunk, w.wid, payload, newly)
-                    if tr is not None:
-                        # EXEC is only credited at report time in this
-                        # mode (work a worker dies holding never counts)
-                        tr.event(trc.EV_EXEC, t_exec0 - t0, w.wid,
-                                 chunk.seq, chunk.start, chunk.size,
-                                 aux=chunk.origin_seq, dt=dt_exec)
-                        tr.event(trc.EV_REPORT, time.monotonic() - t0,
-                                 w.wid, chunk.seq, chunk.start,
-                                 chunk.size, aux=len(newly), dt=dt_exec)
-                    self._feedback(chunk, dt_exec, 0.0)
+                with trc.span("engine.lock_wait"):
+                    self._commit_lock.acquire()
+                try:
+                    with trc.span("engine.report"):
+                        w.tasks_done += chunk.size
+                        self.by_worker[w.wid] = (
+                            self.by_worker.get(w.wid, 0) + chunk.size)
+                        newly = queue.report_tasks(chunk)
+                        self.backend.commit(chunk, w.wid, payload, newly)
+                        if tr is not None:
+                            # EXEC is only credited at report time in
+                            # this mode (work a worker dies holding never
+                            # counts)
+                            tr.event(trc.EV_EXEC, t_exec0 - t0, w.wid,
+                                     chunk.seq, chunk.start, chunk.size,
+                                     aux=chunk.origin_seq, dt=dt_exec)
+                            tr.event(trc.EV_REPORT, time.monotonic() - t0,
+                                     w.wid, chunk.seq, chunk.start,
+                                     chunk.size, aux=len(newly),
+                                     dt=dt_exec)
+                        self._feedback(chunk, dt_exec, 0.0)
+                finally:
+                    self._commit_lock.release()
                 if self.adaptive is not None and not queue.done:
                     # OUTSIDE the commit lock: a decision point may run a
                     # whole forecast sweep, which must not stall other

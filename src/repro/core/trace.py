@@ -42,11 +42,39 @@ from:
     ``chunk_sizes()``, ``overhead_decomposition()``,
     ``dispatch_latency()`` (per-transaction p50/p99 — replacing the
     wall-clock-delta estimate ``benchmarks/fig_cluster.py`` used).
+
+Program spans on the profiler's clock.  :func:`span` is a plain
+``jax.profiler.TraceAnnotation`` (TraceMe): always on, it costs the
+TraceMe check (under 1 us) when no profiler runs, and under
+``jax.profiler.start_trace`` it lands on the ``/host:CPU`` plane, on the
+line of the thread that ran it and on the same clock as the device's
+``XLA Ops``.  The threaded engine and ``runtime.backends.chunk_to_host``
+(the paper loops' chunk entries) open these spans, each under a fixed
+name:
+
+  * ``engine.run`` — the whole ``Engine.run_threaded``, opened at the
+    recorder's ``t0``;
+  * ``engine.request`` — ``queue.request`` (the rDLB queue and its lock);
+  * ``engine.lock_wait`` — asking for the engine's commit lock until it
+    is held;
+  * ``engine.report`` — the commit lock held: ``report_tasks``,
+    ``backend.commit``, the recorder's events, the technique's feedback;
+  * ``chunk.dispatch`` — a chunk entry's input lookups, the ids or grid
+    sent to the device and the jitted call's return;
+  * ``chunk.to_host`` — ``np.asarray`` of the chunk's result: the wait
+    for the device and the copy to the host, one blocking call.
+
+A threaded run's recorder events carry ``t`` in seconds from ``t0``,
+which is taken as the ``engine.run`` span opens, so an event's instant
+on the profiler's clock is that span's start plus ``t``
+(``start_ns + 1e9 * t``); an ``EV_EXEC`` so placed falls on its chunk's
+``chunk.dispatch`` span (``chipbench/test_chipbench_spans.py``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import threading
 from typing import Any, Optional
@@ -57,7 +85,7 @@ __all__ = [
     "EV_ASSIGN", "EV_REISSUE", "EV_EXEC", "EV_REPORT", "EV_COMMIT",
     "EV_DEATH", "EV_FREEZE", "EV_THAW", "EV_CHAOS", "EV_DECISION",
     "EV_FF_SPAN", "EVENT_NAMES", "TraceRecorder", "Trace",
-    "to_chrome", "save_chrome", "load_trace", "summarize", "diff",
+    "to_chrome", "save_chrome", "load_trace", "summarize", "diff", "span",
 ]
 
 TRACE_VERSION = 1
@@ -109,6 +137,20 @@ CHUNK_EVENTS = 1 << 16
 _COLS = ("kind", "t", "wid", "seq", "start", "size", "aux", "dt")
 _DTYPES = dict(kind=np.int8, t=np.float64, wid=np.int32, seq=np.int64,
                start=np.int64, size=np.int64, aux=np.int64, dt=np.float64)
+
+
+@functools.cache
+def _annotation():
+    # imported on first use: processes that only record events (the
+    # cluster's workers) never load JAX for it
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+def span(name: str):
+    """A span of the program on the profiler's clock (see the module
+    docstring): ``with span("engine.request"): ...``."""
+    return _annotation()(name)
 
 
 class TraceRecorder:

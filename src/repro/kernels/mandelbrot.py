@@ -63,4 +63,5 @@ def mandelbrot(c_real: jax.Array, c_imag: jax.Array, *,
                   pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
+        name="mandelbrot",
     )(c_real, c_imag)

@@ -112,5 +112,6 @@ def spin_image(points: jax.Array, centers: jax.Array, normals: jax.Array,
         out_shape=jax.ShapeDtypeStruct((ctr.shape[0], n_beta, n_alpha),
                                        jnp.float32),
         scratch_shapes=[pltpu.VMEM((ROWS, n_beta, n_alpha), jnp.float32)],
+        name="spin_image",
     )(pts, ctr, nrm)
     return out[:Bo]

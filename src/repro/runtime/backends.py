@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace as trc
 from repro.core.engine import WorkerBackend
 from repro.core.rdlb import Chunk
 
@@ -60,6 +61,19 @@ class FnBackend(WorkerBackend):
             return
         for t in newly:
             self.results[t] = payload[t]
+
+
+def chunk_to_host(dispatch: Callable[[], Any]) -> np.ndarray:
+    """A chunk entry's device program, and its result brought to the
+    host, as two program spans (``core/trace.py`` names them):
+    ``chunk.dispatch`` is ``dispatch()`` (the input lookups, the inputs
+    sent to the device, the jitted call's return), ``chunk.to_host`` the
+    ``np.asarray`` of its result, which asks for the copy, waits for the
+    device and copies in one blocking call."""
+    with trc.span("chunk.dispatch"):
+        out = dispatch()
+    with trc.span("chunk.to_host"):
+        return np.asarray(out)
 
 
 class ChunkBackend(WorkerBackend):
