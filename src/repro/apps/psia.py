@@ -21,7 +21,7 @@ from repro.runtime.backends import chunk_to_host
 PAPER_N = 20_000           # oriented points (tasks)
 CLOUD = 16_384             # cloud points binned per task
 N_ALPHA = N_BETA = 64
-BLOCK_P = 1024             # cloud points binned per kernel grid step
+BLOCK_P = 4096             # cloud points binned per kernel grid step
 
 
 @functools.lru_cache(maxsize=2)

@@ -37,9 +37,11 @@ def spin_image(points: jax.Array, centers: jax.Array, normals: jax.Array,
     normal), histogram the cloud in (alpha, beta) cylinder coordinates.
 
     points: (Np, 3); centers/normals: (Bo, 3) -> (Bo, n_beta, n_alpha)."""
-    d = points[None, :, :] - centers[:, None, :]            # (Bo,Np,3)
-    beta = jnp.einsum("bpd,bd->bp", d, normals)             # (Bo,Np)
-    r2 = jnp.sum(d * d, axis=-1)
+    dx, dy, dz = (points[None, :, k] - centers[:, k, None]
+                  for k in range(3))                          # (Bo,Np)
+    nx, ny, nz = (normals[:, k, None] for k in range(3))
+    beta = (dx * nx + dy * ny) + dz * nz
+    r2 = (dx * dx + dy * dy) + dz * dz
     alpha = jnp.sqrt(jnp.maximum(r2 - beta * beta, 0.0))
     ai = jnp.floor(alpha / alpha_max * n_alpha).astype(jnp.int32)
     bi = jnp.floor((beta + beta_max) / (2 * beta_max)

@@ -1,5 +1,7 @@
 """Pallas kernel sweeps (interpret mode) vs the pure-jnp oracles."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,10 +27,41 @@ def test_mandelbrot_matches_ref(side, bm, bn, max_iters):
 
 
 # --------------------------------------------------------------- spin image
-@pytest.mark.parametrize("np_pts,bo,block_p", [(257, 3, 64), (1024, 7, 256),
-                                               (100, 1, 128)])
+def _edge_cloud(ctr, nrm, np_pts, na, nb, amax, bmax):
+    """Cloud points on the (alpha, beta) bin edges of the oriented points
+    (point i on those of oriented point i % Bo).  For an oriented point in
+    general position each lands within a rounding of its edge, on either
+    side, so that only the same operations in the same order bin it
+    alike; for one at the origin along z, with edges on dyadic fractions,
+    the points and their cylinder coordinates are exact."""
+    rng = np.random.default_rng(np_pts)
+    c = np.asarray(ctr, np.float64)
+    n = np.asarray(nrm, np.float64)
+    u = np.cross(n, [0.0, 1.0, 0.0])
+    u /= np.linalg.norm(u, axis=-1, keepdims=True)
+    v = np.cross(n, u)
+    own = np.arange(np_pts) % len(c)
+    turn = np.array([[1, 0], [0, 1], [-1, 0], [0, -1]])[
+        rng.integers(0, 4, np_pts)]
+    a = rng.integers(0, na + 1, np_pts)[:, None] * amax / na
+    b = rng.integers(0, nb + 1, np_pts)[:, None] * (2 * bmax) / nb - bmax
+    pts = (c[own] + b * n[own]
+           + a * (turn[:, :1] * u[own] + turn[:, 1:] * v[own]))
+    return jnp.asarray(pts, jnp.float32)
+
+
+_SPIN_CASES = [(257, 3, 64), (1024, 7, 256), (100, 1, 128),
+               # FAC chunk sizes: pad rows, several row blocks, and a
+               # partial last block of 1,024 points
+               (1300, 1, 1024), (1300, 8, 1024), (1300, 9, 1024),
+               (1300, 39, 1024)]
+
+
+@pytest.mark.parametrize("np_pts,bo,block_p,edges", [
+    pytest.param(*c, False, id="-".join(map(str, c))) for c in _SPIN_CASES
+] + [pytest.param(2048, 3, 512, True, id="2048-3-512-edges")])
 @pytest.mark.parametrize("na,nb", [(32, 16), (64, 64)])
-def test_spin_image_matches_ref(np_pts, bo, block_p, na, nb):
+def test_spin_image_matches_ref(np_pts, bo, block_p, edges, na, nb):
     k = jax.random.PRNGKey(np_pts + bo)
     k1, k2, k3 = jax.random.split(k, 3)
     pts = jax.random.normal(k1, (np_pts, 3), jnp.float32)
@@ -36,10 +69,16 @@ def test_spin_image_matches_ref(np_pts, bo, block_p, na, nb):
     nrm = jax.random.normal(k3, (bo, 3), jnp.float32)
     nrm = nrm / jnp.linalg.norm(nrm, axis=-1, keepdims=True)
     kw = dict(n_alpha=na, n_beta=nb, alpha_max=2.5, beta_max=2.5)
+    if edges:   # oriented point 0 at the origin along z: exact edges
+        ctr = ctr.at[0].set(0.0)
+        nrm = nrm.at[0].set(jnp.array([0.0, 0.0, 1.0]))
+        pts = _edge_cloud(ctr, nrm, np_pts, na, nb, 2.5, 2.5)
     got = ops.spin_image(pts, ctr, nrm, block_p=block_p, **kw)
-    want = ref.spin_image(pts, ctr, nrm, **kw)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5)
+    # the oracle compiled as the interpreted kernel is, so that both take
+    # the same floating-point contractions
+    want = jax.jit(functools.partial(ref.spin_image, **kw))(pts, ctr, nrm)
+    # counts: the kernel's histogram is the reference's, bin for bin
+    assert np.array_equal(np.asarray(got), np.asarray(want))
     # histogram mass = number of in-range points, never more than Np
     assert float(got.sum()) <= bo * np_pts
 

@@ -55,7 +55,7 @@ def test_mandelbrot_compiles(one_chip, side, tile):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("bo", [1, 16])
+@pytest.mark.parametrize("bo", [1, 16, 9, 39])
 def test_spin_image_compiles(one_chip, bo):
     hlo = _hlo(ops.spin_image, one_chip, ((psia.CLOUD, 3), jnp.float32),
                ((bo, 3), jnp.float32), ((bo, 3), jnp.float32),
