@@ -126,7 +126,7 @@ def paper_loops(*, side: int = 512, tile: int = 64, max_iters: int = 256,
     from repro.apps import mandelbrot, psia
     from repro.kernels import ops, ref
 
-    # ---- Mandelbrot: tiles [start, stop) as one kernel launch
+    # ---- Mandelbrot: tiles [start, stop) as one device program
     n = mandelbrot.n_tiles(side, tile)
     tiles, rec_m = _loop_pair(
         "mandelbrot",
@@ -142,8 +142,9 @@ def paper_loops(*, side: int = 512, tile: int = 64, max_iters: int = 256,
     rec_m["oracle_mismatch_pixels"] = int((img != want).sum())
     rec_m["oracle_max_count_diff"] = int(np.abs(img - want).max())
     rec_m["mosaic_kernel"] = _kernel_compiled(
-        ops.mandelbrot, cr[:tile, :tile], ci[:tile, :tile],
-        max_iters=max_iters, bm=tile, bn=tile)
+        mandelbrot.mandelbrot_chunk, *mandelbrot.plane(side), np.int32(0),
+        n=mandelbrot.slab_pixels(tile * tile), tile=tile,
+        max_iters=max_iters)
 
     # ---- PSIA: spin images of oriented points [start, stop), one launch
     images, rec_p = _loop_pair(

@@ -8,15 +8,18 @@ tasks.  Two faces here:
     simulator, derived from the REAL escape counts of the assigned region
     (time proportional to iterations executed) — this reproduces the
     paper's variance structure instead of assuming a distribution;
-  * ``compute_tile()/compute_tiles()`` — the actual JAX/Pallas compute,
-    used by the runtime examples (rDLB re-executing real tiles after
+  * ``compute_tile()/compute_tiles()`` — the actual JAX/Pallas compute of
+    a chunk of ``tile x tile`` tasks (one-pixel tasks at ``tile=1``, the
+    paper's), used by the runtime (rDLB re-executing real tasks after
     injected failures, asserting the final image is loss-less).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -27,6 +30,8 @@ REGION = (-2.0, 0.6, -1.3, 1.3)        # the classic view
 PAPER_N = 262_144                      # 512 x 512
 SIDE = 512
 MAX_ITERS = 256
+LANES = 128
+MIN_PIXELS = 8 * LANES                 # one (8, 128) block of the chip
 
 
 def grid(side: int = SIDE):
@@ -70,22 +75,60 @@ def compute_tile(tile_id: int, *, side: int = SIDE, tile: int = 64,
                          max_iters=max_iters)[0]
 
 
+@functools.lru_cache(maxsize=4)
+def plane(side: int = SIDE):
+    """The grid's c values on the device, flat in row-major pixel order,
+    made once per side."""
+    return tuple(c.reshape(-1) for c in grid(side))
+
+
+def _pixel_ids(start, n: int, side: int, tile: int):
+    """Flat ids of the first ``n`` pixels of tiles ``start, start+1, ...``
+    (row-major tile ids), each tile's square in row-major order; pixels
+    past the grid's last tile repeat a pixel of that tile."""
+    per_row, per_tile = side // tile, tile * tile
+    p = jnp.arange(n, dtype=jnp.int32)
+    t = jnp.minimum(start + p // per_tile, per_row * per_row - 1)
+    q = p % per_tile
+    return ((t // per_row * tile + q // tile) * side
+            + t % per_row * tile + q % tile)
+
+
+def slab_pixels(pixels: int) -> int:
+    """Pixels of the slab that a chunk of ``pixels`` runs in: a power of
+    two of at least ``MIN_PIXELS``, so that chunk sizes share programs."""
+    return max(MIN_PIXELS, 1 << (pixels - 1).bit_length())
+
+
+@functools.partial(jax.jit, static_argnames=("n", "tile", "max_iters"))
+def mandelbrot_chunk(cr, ci, start, *, n: int, tile: int,
+                     max_iters: int):
+    """Escape counts of ``n`` pixels of tiles from ``start`` on, as one
+    lane-dense (n/128, 128) slab: every chunk of at most ``n`` pixels,
+    wherever it starts, runs this one program."""
+    with jax.named_scope("mandelbrot.lookup"):
+        ids = _pixel_ids(start, n, math.isqrt(cr.shape[0]), tile)
+        cr, ci = (c[ids].reshape(n // LANES, LANES) for c in (cr, ci))
+    return mandelbrot_kernel(cr, ci, max_iters=max_iters, bm=8, bn=LANES)
+
+
 def compute_tiles(start: int, stop: int, *, side: int = SIDE,
                   tile: int = 64, max_iters: int = MAX_ITERS) -> np.ndarray:
-    """Compute tiles ``[start, stop)`` (row-major tile ids) as ONE kernel
-    launch: the tiles are stacked into a (k*tile, tile) grid whose kernel
-    blocks are the tiles themselves.  Returns (k, tile, tile); each tile
-    equals ``compute_tile`` of its id."""
-    per_row = side // tile
+    """Compute tiles ``[start, stop)`` (row-major tile ids) as ONE device
+    program: their pixels are gathered from the grid into a slab padded
+    to a power of two of at least ``MIN_PIXELS``, and the pad is dropped
+    on the host.  Returns (k, tile, tile); each tile equals
+    ``compute_tile`` of its id."""
+    k = stop - start
+    pixels = k * tile * tile
 
     def dispatch():
-        cr, ci = (g.reshape(per_row, tile, per_row, tile)
-                  .transpose(0, 2, 1, 3)
-                  .reshape(per_row * per_row, tile, tile)[start:stop]
-                  .reshape(-1, tile) for g in grid(side))
-        return mandelbrot_kernel(cr, ci, max_iters=max_iters, bm=tile,
-                                 bn=tile)
-    return chunk_to_host(dispatch).reshape(-1, tile, tile)
+        cr, ci = plane(side)
+        return mandelbrot_chunk(cr, ci, np.int32(start),
+                                n=slab_pixels(pixels), tile=tile,
+                                max_iters=max_iters)
+    return (chunk_to_host(dispatch).reshape(-1)[:pixels]
+            .reshape(k, tile, tile))
 
 
 def n_tiles(side: int = SIDE, tile: int = 64) -> int:

@@ -59,8 +59,8 @@ name:
     is held;
   * ``engine.report`` — the commit lock held: ``report_tasks``,
     ``backend.commit``, the recorder's events, the technique's feedback;
-  * ``chunk.dispatch`` — a chunk entry's input lookups, the ids or grid
-    sent to the device and the jitted call's return;
+  * ``chunk.dispatch`` — a chunk entry's input lookups, the ids or the
+    chunk's start sent to the device and the jitted call's return;
   * ``chunk.to_host`` — ``np.asarray`` of the chunk's result: the wait
     for the device and the copy to the host, one blocking call.
 

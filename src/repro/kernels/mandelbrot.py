@@ -3,16 +3,19 @@
 The paper's high-task-time-variance application (Table 1: N=262,144
 iterations with "high variability among iterations") — variance comes from
 the escape-time loop: interior points burn max_iters, exterior escape
-early.  The rDLB experiments schedule *rows/tiles* of this grid as tasks.
+early.  The runtime schedules pixels (or square tiles of them) as tasks;
+``apps/mandelbrot.py`` gathers a chunk's pixels into one lane-dense
+(rows, 128) slab for this kernel.
 
 TPU mapping: grid over (M/bm, N/bn) VMEM tiles, both axes parallel; the
 escape loop is a fori_loop over fused VPU ops on the whole (bm, bn) tile.
 Escaped lanes are frozen (masked select) — no divergence penalty on the
-VPU, and no NaN pollution from diverged z values.  Tile 256x256 f32 ~
-256 KB/operand in VMEM: far under the 16 MB budget, big enough to amortize
-grid overhead.  The int32 escape counter starts from the input tile, not
-from a constant: Mosaic refuses to relayout a loop-carried vector that
-starts replicated (a splat) and leaves the loop unreplicated.
+VPU, and no NaN pollution from diverged z values.  Mosaic takes blocks
+whose last two sizes are multiples of 8 and 128 (or the whole array's);
+the runtime's (8, 128) blocks hold each operand of the escape loop in one
+vreg.  The int32 escape counter starts from the input tile, not from a
+constant: Mosaic refuses to relayout a loop-carried vector that starts
+replicated (a splat) and leaves the loop unreplicated.
 """
 
 from __future__ import annotations

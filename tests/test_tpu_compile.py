@@ -55,6 +55,17 @@ def test_mandelbrot_compiles(one_chip, side, tile):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("tile,pixels", [(1, 512), (64, 64 * 64)])
+def test_mandelbrot_chunk_compiles(one_chip, tile, pixels):
+    """The runtime's chunk program: a FAC chunk of 512 one-pixel tasks,
+    and one 64 x 64 tile, each in its lane-dense slab."""
+    flat = ((mandel_app.SIDE ** 2,), jnp.float32)
+    hlo = _hlo(mandel_app.mandelbrot_chunk, one_chip, flat, flat,
+               ((), jnp.int32), n=mandel_app.slab_pixels(pixels),
+               tile=tile, max_iters=mandel_app.MAX_ITERS)
+    assert "tpu_custom_call" in hlo
+
+
 @pytest.mark.parametrize("bo", [1, 16, 9, 39])
 def test_spin_image_compiles(one_chip, bo):
     hlo = _hlo(ops.spin_image, one_chip, ((psia.CLOUD, 3), jnp.float32),
