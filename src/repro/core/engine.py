@@ -103,8 +103,11 @@ class WorkerBackend:
     ``cost`` is the chunk's nominal compute seconds on an unperturbed
     worker (the engine divides by worker speed);
     ``commit`` applies the payload for exactly the task ids this report
-    newly finished (exactly-once / first-completion-wins reduction) —
-    called under the engine's commit lock in threaded mode.
+    newly finished (exactly-once / first-completion-wins reduction).  In
+    threaded mode it runs after the engine's commit lock is released, so
+    several worker threads may be inside ``commit`` at once, each call
+    with ids no other call gets; a backend that reduces into shared
+    state serialises itself.
     """
 
     def execute(self, chunk: rdlb.Chunk, wid: int) -> Any:
@@ -645,7 +648,6 @@ class Engine:
                         self.by_worker[w.wid] = (
                             self.by_worker.get(w.wid, 0) + chunk.size)
                         newly = queue.report_tasks(chunk)
-                        self.backend.commit(chunk, w.wid, payload, newly)
                         if tr is not None:
                             # EXEC is only credited at report time in
                             # this mode (work a worker dies holding never
@@ -660,6 +662,12 @@ class Engine:
                         self._feedback(chunk, dt_exec, 0.0)
                 finally:
                     self._commit_lock.release()
+                # Outside the lock: the queue's transaction made ``newly``
+                # disjoint from every other report's ids, and
+                # ``run_threaded`` joins this thread before anything
+                # reads the backend's results.
+                with trc.span("engine.commit"):
+                    self.backend.commit(chunk, w.wid, payload, newly)
                 if self.adaptive is not None and not queue.done:
                     # OUTSIDE the commit lock: a decision point may run a
                     # whole forecast sweep, which must not stall other

@@ -57,8 +57,11 @@ name:
   * ``engine.request`` — ``queue.request`` (the rDLB queue and its lock);
   * ``engine.lock_wait`` — asking for the engine's commit lock until it
     is held;
-  * ``engine.report`` — the commit lock held: ``report_tasks``,
-    ``backend.commit``, the recorder's events, the technique's feedback;
+  * ``engine.report`` — the commit lock held: ``report_tasks``, the
+    recorder's events, the technique's feedback;
+  * ``engine.commit`` — ``backend.commit``, after the commit lock is
+    released: each call gets ids no other report won, so several
+    workers' commits may be open at once;
   * ``chunk.dispatch`` — a chunk entry's input lookups, the ids or the
     chunk's start sent to the device and the jitted call's return;
   * ``chunk.to_host`` — ``np.asarray`` of the chunk's result: the wait

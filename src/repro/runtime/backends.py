@@ -15,11 +15,14 @@ defines what a chunk of tasks IS:
 
 Backends never talk to the queue; ``commit`` receives the task ids its
 report newly finished, so a duplicate's payload is applied only for tasks
-it won.  ``commit`` runs under the engine's commit lock in threaded mode.
+it won.  In threaded mode ``commit`` may run concurrently from several
+worker threads, each call with ids no other call gets; a backend that
+reduces into shared state serialises itself.
 """
 
 from __future__ import annotations
 
+import threading
 from typing import Any, Callable, Optional, Sequence
 
 import jax
@@ -59,6 +62,7 @@ class FnBackend(WorkerBackend):
                newly: list[int]) -> None:
         if payload is None:
             return
+        # lock-free: concurrent calls set disjoint keys
         for t in newly:
             self.results[t] = payload[t]
 
@@ -83,13 +87,15 @@ class ChunkBackend(WorkerBackend):
 
     ``commit`` writes the rows a report newly finished into ``results``
     (``n_tasks`` rows), exactly once by task id: a duplicate's rows land
-    only for tasks it won."""
+    only for tasks it won.  Concurrent commits copy their disjoint rows
+    without a lock; only the first allocation of ``results`` takes one."""
 
     def __init__(self, chunk_fn: Callable[[int, int], Any],
                  n_tasks: int) -> None:
         self.chunk_fn = chunk_fn
         self.n_tasks = n_tasks
         self.results: Optional[np.ndarray] = None
+        self._alloc_lock = threading.Lock()
 
     def execute(self, chunk: Chunk, wid: int) -> Any:
         return np.asarray(self.chunk_fn(chunk.start, chunk.stop))
@@ -97,8 +103,10 @@ class ChunkBackend(WorkerBackend):
     def commit(self, chunk: Chunk, wid: int, payload: Any,
                newly: list[int]) -> None:
         if self.results is None:
-            self.results = np.zeros((self.n_tasks,) + payload.shape[1:],
-                                    payload.dtype)
+            with self._alloc_lock:
+                if self.results is None:
+                    self.results = np.zeros(
+                        (self.n_tasks,) + payload.shape[1:], payload.dtype)
         rows = np.asarray(newly, dtype=np.int64)
         self.results[rows] = payload[rows - chunk.start]
 
@@ -125,25 +133,27 @@ class TrainBackend(WorkerBackend):
         self.grad_acc = None
         self.loss_sum = 0.0
         self.n_done = 0
+        self._lock = threading.Lock()   # commits reduce into shared state
 
     def execute(self, chunk: Chunk, wid: int) -> Any:
         return {t: self.grad_fn(t) for t in chunk.tasks()}
 
     def commit(self, chunk: Chunk, wid: int, payload: Any,
                newly: list[int]) -> None:
-        for t in newly:
-            loss, grads = payload[t]
-            self.loss_sum += float(loss)
-            self.n_done += 1
-            if self.exact:
-                self.per_task[t] = grads
-            elif self.grad_acc is None:
-                self.grad_acc = jax.tree_util.tree_map(
-                    lambda g: g.astype(jnp.float32), grads)
-            else:
-                self.grad_acc = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(jnp.float32),
-                    self.grad_acc, grads)
+        with self._lock:
+            for t in newly:
+                loss, grads = payload[t]
+                self.loss_sum += float(loss)
+                self.n_done += 1
+                if self.exact:
+                    self.per_task[t] = grads
+                elif self.grad_acc is None:
+                    self.grad_acc = jax.tree_util.tree_map(
+                        lambda g: g.astype(jnp.float32), grads)
+                else:
+                    self.grad_acc = jax.tree_util.tree_map(
+                        lambda a, g: a + g.astype(jnp.float32),
+                        self.grad_acc, grads)
 
     def reduced(self) -> Any:
         """Final accumulated gradients (fixed task order when exact)."""
@@ -180,6 +190,7 @@ class ServeBackend(WorkerBackend):
 
     def commit(self, chunk: Chunk, wid: int, payload: Any,
                newly: list[int]) -> None:
+        # lock-free: concurrent calls write fields of disjoint requests
         for rid in newly:
             req = self.requests[rid]
             req.output = payload[rid]

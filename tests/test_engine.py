@@ -2,6 +2,10 @@
 batched-decode equivalence, threaded concurrency, and the duplicate-count
 bookkeeping regression."""
 
+import sys
+import threading
+import time
+
 import jax
 import numpy as np
 import pytest
@@ -11,7 +15,7 @@ from repro.data import batch_for_step
 from repro.models import build_model
 from repro.models.config import ModelConfig
 from repro.runtime import RDLBServeExecutor, RDLBTrainExecutor, Request
-from repro.runtime.backends import FnBackend
+from repro.runtime.backends import ChunkBackend, FnBackend, TrainBackend
 
 CFG = ModelConfig(family="dense", n_layers=2, d_model=64, n_heads=4,
                   n_kv_heads=2, d_ff=128, vocab_size=128)
@@ -197,6 +201,94 @@ def test_concurrent_serve_hang_without_rdlb(serve_setup):
                            rdlb_enabled=False, concurrent=True)
     stats = ex.serve(reqs, fail_at={1: 0})
     assert stats.hung
+
+
+# ------------------------------------- commits outside the engine's lock
+def test_threaded_commits_run_concurrently():
+    """Two live workers' commits are inside ``commit`` at once: each
+    waits for the other at a two-party barrier, which times out (and
+    the run raises) if the engine serialises its commits."""
+
+    class MeetingBackend(engine.WorkerBackend):
+        def __init__(self):
+            self.barrier = threading.Barrier(2)
+            self.committed = []
+
+        def commit(self, chunk, wid, payload, newly):
+            self.barrier.wait(timeout=10.0)
+            self.committed.extend(newly)
+
+    backend = MeetingBackend()
+    queue = rdlb.RobustQueue(2, dls.make_technique("SS", 2, 2))
+    eng = engine.Engine(queue, [engine.EngineWorker(wid=i)
+                                for i in range(2)], backend)
+    st = eng.run_threaded(stall_timeout=30.0)
+    assert not st.hung and st.n_finished == 2
+    assert sorted(backend.committed) == [0, 1]
+
+
+class _AllocCountingChunkBackend(ChunkBackend):
+    """A ``ChunkBackend`` that counts allocations of ``results``.  A read
+    that finds no array yet waits 20 ms, so the first commits of a run
+    all see ``None`` and race to allocate."""
+
+    def __init__(self, *args):
+        self._results, self.allocs = None, 0
+        super().__init__(*args)
+
+    @property
+    def results(self):
+        out = self._results
+        if out is None:
+            time.sleep(0.02)
+        return out
+
+    @results.setter
+    def results(self, value):
+        if value is not None:
+            self.allocs += 1
+        self._results = value
+
+
+@pytest.mark.parametrize("kind", ["chunk", "train"])
+def test_threaded_commits_exactly_once_under_races(kind):
+    """P = 32 threads with a straggler and a count-based fail-stop, so
+    duplicates race their originals and commits overlap: every task is
+    applied exactly once, by the report that won it.  A short switch
+    interval makes a lost update in a backend's reduction likely."""
+    N, P, width = 512, 32, 4096
+    if kind == "chunk":
+        def chunk_fn(start, stop):
+            return np.repeat(np.arange(start, stop, dtype=np.float32)[:, None],
+                             width, axis=1)
+        backend = _AllocCountingChunkBackend(chunk_fn, N)
+    else:
+        backend = TrainBackend(
+            lambda t: (float(t), {"w": np.full(width, t, np.float32)}))
+    workers = [engine.EngineWorker(wid=i) for i in range(P)]
+    workers[0].sleep_per_task = 0.02          # straggler
+    workers[1].fail_after_tasks = 0           # dies holding its 1st chunk
+    queue = rdlb.RobustQueue(N, dls.make_technique("FAC", N, P))
+    eng = engine.Engine(queue, workers, backend)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        st = eng.run_threaded(stall_timeout=30.0)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not st.hung and st.n_finished == N
+    assert st.n_duplicates > 0 and 1 not in st.survivors
+    if kind == "chunk":
+        want = np.repeat(np.arange(N, dtype=np.float32)[:, None], width,
+                         axis=1)
+        assert backend.allocs == 1
+        np.testing.assert_array_equal(backend.results, want)
+    else:
+        assert backend.n_done == N
+        assert backend.loss_sum == float(sum(range(N)))
+        np.testing.assert_array_equal(
+            np.asarray(backend.reduced()["w"]),
+            np.full(width, sum(range(N)), np.float32))
 
 
 # ---------------------------------------------- spec/legacy parity suite
