@@ -10,7 +10,7 @@ import numpy as np
 
 from repro import api
 from repro.apps import mandelbrot, psia
-from repro.core import dls, faults
+from repro.core import dls
 
 ARTIFACTS = Path(__file__).resolve().parent.parent / "artifacts" / "bench"
 
@@ -37,11 +37,12 @@ def apps(quick: bool = True):
 
 def scenarios(t_estimate: float, seed: int = 0):
     """The seven Table-1 execution scenarios at P=256."""
-    sc = faults.paper_scenarios(P, t_exec_estimate=t_estimate, seed=seed)
-    return sc
+    return api.scenarios.paper_scenarios(P, t_exec_estimate=t_estimate,
+                                         seed=seed)
 
 
-def spec_for(technique: str, scenario, *, rdlb: bool = True,
+def spec_for(technique: str, cluster: api.ClusterSpec, *,
+             rdlb: bool = True,
              seed: int = 0, h: float = 1e-4) -> api.RunSpec:
     """One Table-1 grid cell as a declarative RunSpec — the benchmarks'
     scenario vocabulary (serializable; the ``python -m repro`` CLI runs
@@ -49,15 +50,15 @@ def spec_for(technique: str, scenario, *, rdlb: bool = True,
     return api.RunSpec(
         scheduling=api.SchedulingSpec(technique=technique, seed=seed),
         robustness=api.RobustnessSpec(rdlb_enabled=rdlb),
-        cluster=api.ClusterSpec.from_scenario(scenario),
+        cluster=cluster,
         execution=api.ExecutionSpec(h=h),
-        name=f"{scenario.name}/{technique}")
+        name=f"{cluster.name}/{technique}")
 
 
-def run_one(task_times, technique: str, scenario, *, rdlb: bool,
-            seed: int = 0):
+def run_one(task_times, technique: str, cluster: api.ClusterSpec, *,
+            rdlb: bool, seed: int = 0):
     t0 = time.time()
-    r = api.simulate(spec_for(technique, scenario, rdlb=rdlb, seed=seed),
+    r = api.simulate(spec_for(technique, cluster, rdlb=rdlb, seed=seed),
                      task_times)
     return r, time.time() - t0
 

@@ -16,14 +16,18 @@ from __future__ import annotations
 import csv
 import dataclasses
 import json
+import sys
 import time
 from pathlib import Path
+
+if __package__ in (None, ""):        # `python benchmarks/fig4_resilience.py`
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import numpy as np
 
 from benchmarks import common
 from repro import api
-from repro.core import faults, robustness
+from repro.core import robustness
 
 
 def load_fig3(app: str):
@@ -77,14 +81,14 @@ def emit_spec(out=None, *, app: str = "psia", scenario: str = "fail_1",
     assert workload is not None, "explicit task_times need a workload dict"
     techniques = list(techniques or
                       (t for t in common.TECHNIQUES if t != "STATIC"))
-    base_sc = faults.baseline(P)
+    base_sc = api.scenarios.baseline(P)
     t_est = api.simulate(common.spec_for("FAC", base_sc, h=h),
                          task_times).t_par
-    scenarios = faults.paper_scenarios(P, t_exec_estimate=t_est, seed=seed)
+    scenarios = api.scenarios.paper_scenarios(P, t_exec_estimate=t_est,
+                                              seed=seed)
     sweep = []
     for scen in ("baseline", scenario):
-        cluster = dataclasses.asdict(
-            api.ClusterSpec.from_scenario(scenarios[scen]))
+        cluster = dataclasses.asdict(scenarios[scen])
         for tech in techniques:
             sweep.append({
                 "name": f"{scen}/{tech}",
@@ -109,8 +113,8 @@ def emit_spec(out=None, *, app: str = "psia", scenario: str = "fail_1",
 
 # --------------------------------------------------------- Monte-Carlo mode
 def _draw_failures(rng, P, k, t_est, draws):
-    """``draws`` i.i.d. instances of ``faults.failures(P, k, ...)`` as a
-    [draws, P] fail-time matrix (inf = survives): k distinct victims from
+    """``draws`` i.i.d. instances of ``api.scenarios.failures(P, k, ...)``
+    as a [draws, P] fail-time matrix (inf = survives): k distinct victims from
     1..P-1 (the master never fails), times uniform over the paper's
     "arbitrary during execution" window."""
     keys = rng.random((draws, P - 1))
@@ -159,7 +163,7 @@ def monte_carlo(*, P: int = 32, n_tasks: int = 256, t_task: float = 0.01,
     if cells is None:
         cells = (1, P // 2, P - 1)
     from repro.core import devicesim
-    base_sc = faults.baseline(P)
+    base_sc = api.scenarios.baseline(P)
     specs = {t: common.spec_for(t, base_sc, rdlb=1, seed=seed, h=h)
              for t in techniques}
     lows = []
@@ -185,13 +189,13 @@ def monte_carlo(*, P: int = 32, n_tasks: int = 256, t_task: float = 0.01,
         bad = np.flatnonzero(~res.valid)
         for b in bad:
             t_ix, d = divmod(int(b), draws)
-            prof = [faults.PEProfile(
-                        fail_time=None if np.isinf(f) else float(f))
-                    for f in fail[d]]
-            sc = faults.Scenario(f"mc_{k}_{d}", prof)
+            workers = tuple(
+                api.WorkerSpec(fail_time=None if np.isinf(f) else float(f))
+                for f in fail[d])
             sp = dataclasses.replace(
                 specs[techniques[t_ix]],
-                cluster=api.ClusterSpec.from_scenario(sc))
+                cluster=api.ClusterSpec(n_workers=P, workers=workers,
+                                        name=f"mc_{k}_{d}"))
             t_fail[b] = api.simulate(sp, times).t_par
         rho = _rho_per_draw(t_fail.reshape(nt, draws), t_base)
         for t_ix, tech in enumerate(techniques):

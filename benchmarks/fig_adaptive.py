@@ -26,10 +26,11 @@ if __package__ in (None, ""):           # `python benchmarks/fig_adaptive.py`
 import numpy as np
 
 from benchmarks import common
+from repro import api
 from repro.adaptive import (AdaptiveConfig, AdaptiveController, Candidate,
                             DEFAULT_PORTFOLIO, capture, run_adaptive,
                             run_static, sweep)
-from repro.core import dls, engine, faults, rdlb, simulator
+from repro.core import dls, engine, rdlb, simulator
 
 PERTURB = ("pe_perturb", "latency_perturb", "combined_perturb")
 
@@ -43,7 +44,7 @@ def sweep_cost(P: int = 256, N: int = 8192, *,
     tech = dls.make_technique("FAC", N, P)
     queue = rdlb.RobustQueue(N, tech)
     eng = engine.Engine(
-        queue, simulator.workers_from_scenario(faults.pe_perturbation(P)),
+        queue, api.scenarios.pe_perturbation(P).engine_workers(),
         simulator.SimBackend(tt))
     snap = capture(eng, 0.0)
     t0 = time.time()
@@ -108,7 +109,7 @@ def dry_run():
     """Fast CI smoke: tiny scale, one scenario, plus a sweep timing."""
     P, N = 16, 512
     tt = np.abs(np.random.default_rng(0).normal(0.01, 0.004, N)) + 1e-4
-    sc = faults.pe_perturbation(P, node_size=4)
+    sc = api.scenarios.pe_perturbation(P, node_size=4)
     portfolio = (Candidate("FAC"), Candidate("GSS"), Candidate("mFSC"))
     statics = {c.label: run_static(tt, sc, c).t_par
                for c in portfolio}

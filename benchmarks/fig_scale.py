@@ -44,14 +44,13 @@ import numpy as np
 
 from benchmarks import common
 from repro import api
-from repro.core import faults, refqueue, theory
+from repro.core import refqueue, theory
 
 
 def _spec(technique: str, P: int, scenario=None, h: float = 1e-4):
     return api.RunSpec(
         scheduling=api.SchedulingSpec(technique=technique),
-        cluster=api.ClusterSpec.from_scenario(scenario
-                                              or faults.baseline(P)),
+        cluster=scenario or api.scenarios.baseline(P),
         execution=api.ExecutionSpec(h=h))
 
 
@@ -105,7 +104,7 @@ def overhead_points(Ps=(64, 256, 1024), N=1 << 18, t=0.01, seed=0):
     for P in Ps:
         base, _ = _run("SS", P, N, t)
         T = base.t_par
-        sc = faults.failures(P, 1, t_exec_estimate=T, seed=seed)
+        sc = api.scenarios.failures(P, 1, t_exec_estimate=T, seed=seed)
         fail, _ = _run("SS", P, N, t, scenario=sc)
         lam = 1.0 / T                     # one expected failure per run
         rows.append(dict(
@@ -126,7 +125,7 @@ def sweep_cost(P=1024, N=131072, seed=0):
     tech = dls.make_technique("FAC", N, P)
     queue = rdlb.RobustQueue(N, tech)
     eng = engine.Engine(
-        queue, simulator.workers_from_scenario(faults.pe_perturbation(P)),
+        queue, api.scenarios.pe_perturbation(P).engine_workers(),
         simulator.SimBackend(tt))
     snap = capture(eng, 0.0)
     t0 = time.perf_counter()

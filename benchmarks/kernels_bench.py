@@ -122,6 +122,7 @@ def serve_throughput(quick: bool = True):
     same outputs.  Reports req/s and the batched speedup."""
     from repro.models import build_model
     from repro.models.config import ModelConfig
+    from repro import api
     from repro.runtime import RDLBServeExecutor, Request
 
     cfg = ModelConfig(family="dense", n_layers=2, d_model=128, n_heads=4,
@@ -136,8 +137,9 @@ def serve_throughput(quick: bool = True):
     def run(batch_decode: bool) -> tuple[float, list]:
         reqs = [Request(i, p, max_new_tokens=new)
                 for i, p in enumerate(prompts)]
-        ex = RDLBServeExecutor(model, params, n_workers=1,
-                               technique="GSS", batch_decode=batch_decode)
+        ex = RDLBServeExecutor(
+            model, params, spec=api.serve_spec(technique="GSS", n_workers=1),
+            batch_decode=batch_decode)
         ex.serve(reqs)        # warm-up: jit compile at these shapes
         reqs = [Request(i, p, max_new_tokens=new)
                 for i, p in enumerate(prompts)]

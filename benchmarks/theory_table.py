@@ -10,7 +10,8 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks import common
-from repro.core import faults, simulator, theory
+from repro import api
+from repro.core import theory
 
 
 def run(t: float = 0.01, lam: float = 0.01):
@@ -22,10 +23,12 @@ def run(t: float = 0.01, lam: float = 0.01):
         closed = theory.expected_time_one_failure(n, t, q, lam)
         mc = theory.monte_carlo_one_failure(n, t, q, lam, reps=30000)
         # simulator: mean over seeds of exactly-one-failure runs
+        spec = api.RunSpec(scheduling=api.SchedulingSpec(technique="SS"),
+                           execution=api.ExecutionSpec(h=1e-7))
         sims = []
         for seed in range(10):
-            sc = faults.failures(q, 1, t_exec_estimate=T, seed=seed)
-            r = simulator.run(np.full(N, t), "SS", sc, h=1e-7)
+            sc = api.scenarios.failures(q, 1, t_exec_estimate=T, seed=seed)
+            r = api.simulate(spec.replace(cluster=sc), np.full(N, t))
             sims.append(r.t_par)
         rows.append((q, n, closed, mc, float(np.mean(sims)),
                      theory.rdlb_overhead(n, t, q, lam),

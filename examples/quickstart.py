@@ -39,7 +39,7 @@ import numpy as np
 
 from repro import api
 from repro.adaptive import AdaptiveConfig, Candidate, run_adaptive, run_static
-from repro.core import dls, faults, rdlb, simulator, theory
+from repro.core import dls, rdlb, theory
 
 P, N = 8, 1024
 TASK_T = 0.01
@@ -67,11 +67,15 @@ print(f"   finished {s['n_finished']}/{N} tasks with {len(dead)} dead "
 assert queue.done
 
 print("=== 2. Discrete-event simulation: failure vs hang ===")
+# A Table-1 scenario is a ClusterSpec; one RunSpec varies around it.
 tt = np.full(N, TASK_T)
-base = simulator.run(tt, "FAC", faults.baseline(P))
-sc = faults.failures(P, 1, t_exec_estimate=base.t_par, seed=0)
-with_rdlb = simulator.run(tt, "FAC", sc, rdlb_enabled=True)
-without = simulator.run(tt, "FAC", sc, rdlb_enabled=False)
+spec2 = api.RunSpec(scheduling=api.SchedulingSpec(technique="FAC"),
+                    cluster=api.scenarios.baseline(P))
+base = api.simulate(spec2, tt)
+spec2 = spec2.replace(cluster=api.scenarios.failures(
+    P, 1, t_exec_estimate=base.t_par, seed=0))
+with_rdlb = api.simulate(spec2, tt)
+without = api.simulate(spec2.override("robustness.rdlb_enabled", False), tt)
 print(f"   baseline           t_par = {base.t_par:.3f}s")
 print(f"   1 failure + rDLB   t_par = {with_rdlb.t_par:.3f}s")
 print(f"   1 failure, no rDLB t_par = {without.t_par}  <- the paper's hang")
@@ -88,7 +92,7 @@ print("=== 4. Adaptive scheduling: simulate-in-the-loop, hot-swap ===")
 # every scenario, so the controller forecasts a portfolio (by resuming
 # the simulator from a mid-run snapshot) and swaps the queue's technique
 # for the remainder when a candidate predicts a faster finish.
-perturbed = faults.pe_perturbation(P, node_size=P // 2, node=1)
+perturbed = api.scenarios.pe_perturbation(P, node_size=P // 2, node=1)
 portfolio = tuple(Candidate(t) for t in ("FAC", "GSS", "mFSC", "AWF-C"))
 cfg = AdaptiveConfig(portfolio=portfolio, decision_every_chunks=32,
                      min_remaining=16, max_sim_tasks=None)
@@ -183,7 +187,7 @@ P7, N7 = 1024, 1_000_000
 tt7 = np.full(N7, 0.01)
 spec7 = api.RunSpec(
     scheduling=api.SchedulingSpec(technique="SS"),
-    cluster=api.ClusterSpec.from_scenario(faults.baseline(P7)),
+    cluster=api.scenarios.baseline(P7),
     execution=api.ExecutionSpec(h=1e-4))
 t0 = _time.perf_counter()
 r7 = api.simulate(spec7, tt7)

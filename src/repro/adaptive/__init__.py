@@ -27,22 +27,24 @@ from repro.adaptive.controller import (  # noqa: F401
     AdaptiveConfig, AdaptiveController, DecisionRecord,
 )
 from repro.adaptive.forecaster import (  # noqa: F401
-    Candidate, DEFAULT_PORTFOLIO, coarsen_times, forecast_candidate,
-    remaining_times, run_static, scenario_from_snapshot, sweep,
+    cluster_from_snapshot, coarsen_times, forecast_candidate,
+    remaining_times, run_static, sweep,
 )
 from repro.adaptive.snapshot import (  # noqa: F401
     EngineSnapshot, WorkerSnapshot, capture,
 )
+from repro.api.spec import Candidate, DEFAULT_PORTFOLIO  # noqa: F401
 
 
-def run_adaptive(task_times, scenario, *, initial: str = "FAC",
+def run_adaptive(task_times, cluster, *, initial: str = "FAC",
                  config=None, h: float = 1e-4, seed: int = 0):
     """Convenience driver: simulate one run under the adaptive policy.
 
-    Starts from ``initial`` (the controller may immediately re-plan at
-    t=0 when ``plan_at_start`` is on) and returns
-    ``(SimResult, AdaptiveController)`` — decisions are on the
-    controller and on ``EngineStats.adaptive_decisions``.
+    ``cluster`` is the run's ``api.ClusterSpec`` (e.g. a Table-1
+    scenario from ``api.scenarios``).  Starts from ``initial`` (the
+    controller may immediately re-plan at t=0 when ``plan_at_start`` is
+    on) and returns ``(SimResult, AdaptiveController)`` — decisions are
+    on the controller and on ``EngineStats.adaptive_decisions``.
     """
     import numpy as np
 
@@ -53,7 +55,7 @@ def run_adaptive(task_times, scenario, *, initial: str = "FAC",
     spec = api.RunSpec(
         scheduling=api.SchedulingSpec(technique=initial, seed=seed,
                                       params=(("h", h),)),
-        cluster=api.ClusterSpec.from_scenario(scenario),
+        cluster=cluster,
         execution=api.ExecutionSpec(h=h))
     result = api.simulate(spec, np.asarray(task_times, dtype=float),
                           adaptive=ctrl)

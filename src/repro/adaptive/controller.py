@@ -36,8 +36,9 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from repro.adaptive.forecaster import Candidate, DEFAULT_PORTFOLIO, sweep
+from repro.adaptive.forecaster import sweep
 from repro.adaptive.snapshot import capture
+from repro.api.spec import Candidate, DEFAULT_PORTFOLIO
 
 
 @dataclasses.dataclass

@@ -29,20 +29,19 @@ import numpy as np
 
 from repro import api
 from repro.adaptive.snapshot import EngineSnapshot
-# Candidate became a RunSpec delta (repro.api.spec); re-exported here for
-# back-compat with the original portfolio vocabulary.
-from repro.api.spec import Candidate, DEFAULT_PORTFOLIO  # noqa: F401
-from repro.core import dls, faults, simulator
+from repro.api.spec import Candidate, DEFAULT_PORTFOLIO
+from repro.core import dls, simulator
 
 
-def scenario_from_snapshot(snap: EngineSnapshot) -> faults.Scenario:
-    """Worker profiles as known at capture: survivors only, at their
-    current speed/latency.  Future fail-stops are unknowable and absent."""
-    profiles = [faults.PEProfile(speed=w.speed, msg_latency=w.msg_latency)
-                for w in snap.workers if w.alive]
-    if not profiles:                    # all dead: forecast degenerates
-        profiles = [faults.PEProfile()]
-    return faults.Scenario(f"resume@{snap.t:.4g}", profiles)
+def cluster_from_snapshot(snap: EngineSnapshot) -> "api.ClusterSpec":
+    """The cluster as known at capture: survivors only, at their current
+    speed/latency.  Future fail-stops are unknowable and absent."""
+    workers = [api.WorkerSpec(speed=w.speed, msg_latency=w.msg_latency)
+               for w in snap.workers if w.alive]
+    if not workers:                     # all dead: forecast degenerates
+        workers = [api.WorkerSpec()]
+    return api.ClusterSpec(n_workers=len(workers), workers=tuple(workers),
+                           name=f"resume@{snap.t:.4g}")
 
 
 def base_spec_from_snapshot(snap: EngineSnapshot, *, h: float = 1e-4,
@@ -58,7 +57,7 @@ def base_spec_from_snapshot(snap: EngineSnapshot, *, h: float = 1e-4,
             rdlb_enabled=snap.rdlb_enabled,
             max_duplicates=snap.max_duplicates,
             barrier_max_duplicates=snap.barrier_max_duplicates),
-        cluster=api.ClusterSpec.from_scenario(scenario_from_snapshot(snap)),
+        cluster=cluster_from_snapshot(snap),
         execution=api.ExecutionSpec(h=h, horizon=horizon))
 
 
@@ -203,7 +202,7 @@ def sweep(snap: EngineSnapshot, task_times: Sequence[float],
     return preds
 
 
-def run_static(task_times: Sequence[float], scenario: faults.Scenario,
+def run_static(task_times: Sequence[float], cluster: "api.ClusterSpec",
                cand: Candidate, *, h: float = 1e-4, seed: int = 0,
                horizon: float = 1e7) -> simulator.SimResult:
     """Full static run of one candidate, start to finish — the oracle
@@ -212,6 +211,6 @@ def run_static(task_times: Sequence[float], scenario: faults.Scenario,
     base = api.RunSpec(
         scheduling=api.SchedulingSpec(technique="FAC", seed=seed,
                                       params=(("h", h),)),
-        cluster=api.ClusterSpec.from_scenario(scenario),
+        cluster=cluster,
         execution=api.ExecutionSpec(h=h, horizon=horizon))
     return api.simulate(cand.apply(base), times)

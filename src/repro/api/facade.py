@@ -2,7 +2,7 @@
 
 Every driver funnels through :func:`build`:
 
-  * ``repro.core.simulator.simulate``/``run`` (timing-only backend),
+  * :func:`simulate` (``repro.core.simulator.SimBackend``, timing only),
   * ``repro.runtime.RDLBTrainExecutor`` (microbatch gradients),
   * ``repro.runtime.RDLBServeExecutor`` (request decoding),
   * the adaptive forecaster's candidate sweep (resumed remainders),
@@ -14,7 +14,6 @@ full discrete-event simulation of one spec over one workload.
 
 from __future__ import annotations
 
-import warnings
 from typing import Any, Optional, Sequence
 
 import numpy as np
@@ -25,15 +24,7 @@ from repro.core import dls, engine, rdlb
 from repro.core import simulator as _sim
 
 __all__ = ["build", "run", "execute", "simulate", "make_scheduler",
-           "train_spec", "serve_spec", "warn_legacy", "LEGACY_MSG"]
-
-LEGACY_MSG = "legacy keyword API; build a repro.api.RunSpec instead"
-
-
-def warn_legacy(what: str, *, stacklevel: int = 3) -> None:
-    """One shared DeprecationWarning for every legacy-kwarg shim."""
-    warnings.warn(f"{LEGACY_MSG} ({what})", DeprecationWarning,
-                  stacklevel=stacklevel)
+           "train_spec", "serve_spec"]
 
 
 def train_spec(*, technique: str = "FAC", n_workers: int = 4,
@@ -175,8 +166,11 @@ def simulate(spec: RunSpec, task_times: Sequence[float], *,
     The scenario-as-data entry point: everything about the run —
     technique, rDLB knobs, worker perturbations, execution mode,
     adaptive policy — comes from the spec; the workload is the nominal
-    per-task times.  Returns the same :class:`SimResult` as the legacy
-    ``simulator.simulate``.
+    per-task times.  ``technique`` injects a prebuilt (e.g. custom or
+    pre-warmed) technique object in place of the spec's; ``backend``
+    replaces the timing-only :class:`SimBackend` (e.g. an executing
+    ``runtime.FnBackend`` over the same costs runs the schedule the
+    simulation predicts, event for event).
     """
     tt = np.asarray(task_times, dtype=float)
     N = len(tt)

@@ -1,4 +1,4 @@
-"""Failure and perturbation models (paper Table 1, "Execution scenarios").
+"""The paper's execution scenarios (Table 1) as ClusterSpecs.
 
 Scenarios on miniHPC (16 nodes x 16 ranks = 256 PEs):
 
@@ -8,40 +8,41 @@ Scenarios on miniHPC (16 nodes x 16 ranks = 256 PEs):
   Perturbations:  PE availability   — all PEs of one node slowed (CPU burner),
                   Network latency   — +10 s per message to/from one node,
                   Combined          — both at once.
+
+Each constructor returns a :class:`ClusterSpec` named after its scenario,
+with one :class:`WorkerSpec` per worker.
 """
 
 from __future__ import annotations
 
-import dataclasses
-from typing import Optional
-
 import numpy as np
 
+from repro.api.spec import ClusterSpec, WorkerSpec
 
-@dataclasses.dataclass
-class PEProfile:
-    """Static per-PE behaviour for one experiment."""
-    speed: float = 1.0                 # relative compute speed (1.0 nominal)
-    fail_time: Optional[float] = None  # fail-stop instant (None = survives)
-    msg_latency: float = 0.0           # extra seconds per message to/from PE
+__all__ = ["baseline", "failures", "pe_perturbation", "latency_perturbation",
+           "combined_perturbation", "paper_scenarios"]
 
 
-@dataclasses.dataclass
-class Scenario:
-    name: str
-    profiles: list[PEProfile]
-
-    @property
-    def P(self) -> int:
-        return len(self.profiles)
+def _cluster(name: str, workers: list) -> ClusterSpec:
+    return ClusterSpec(n_workers=len(workers), workers=tuple(workers),
+                       name=name)
 
 
-def baseline(P: int) -> Scenario:
-    return Scenario("baseline", [PEProfile() for _ in range(P)])
+def _node(name: str, P: int, node_size: int, node: int,
+          **perturbation) -> ClusterSpec:
+    """Every PE of node ``node`` gets ``perturbation``; the rest nominal."""
+    workers = [WorkerSpec()] * P
+    for pe in range(node * node_size, min(P, (node + 1) * node_size)):
+        workers[pe] = WorkerSpec(**perturbation)
+    return _cluster(name, workers)
+
+
+def baseline(P: int) -> ClusterSpec:
+    return _cluster("baseline", [WorkerSpec()] * P)
 
 
 def failures(P: int, n_failures: int, *, t_exec_estimate: float,
-             seed: int = 0) -> Scenario:
+             seed: int = 0) -> ClusterSpec:
     """``n_failures`` distinct non-master PEs die at arbitrary times.
 
     Fail times are drawn uniformly over (0, t_exec_estimate) — "occur
@@ -53,42 +54,33 @@ def failures(P: int, n_failures: int, *, t_exec_estimate: float,
     victims = rng.choice(np.arange(1, P), size=n_failures, replace=False)
     times = rng.uniform(0.05 * t_exec_estimate, 0.95 * t_exec_estimate,
                         size=n_failures)
-    profiles = [PEProfile() for _ in range(P)]
+    workers = [WorkerSpec()] * P
     for v, t in zip(victims, times):
-        profiles[int(v)].fail_time = float(t)
-    return Scenario(f"fail_{n_failures}", profiles)
+        workers[int(v)] = WorkerSpec(fail_time=float(t))
+    return _cluster(f"fail_{n_failures}", workers)
 
 
 def pe_perturbation(P: int, *, node_size: int = 16, node: int = 1,
-                    slowdown: float = 0.25) -> Scenario:
+                    slowdown: float = 0.25) -> ClusterSpec:
     """All PEs on one node compute at ``slowdown`` x nominal (CPU burner)."""
-    profiles = [PEProfile() for _ in range(P)]
-    for pe in range(node * node_size, min(P, (node + 1) * node_size)):
-        profiles[pe].speed = slowdown
-    return Scenario("pe_perturb", profiles)
+    return _node("pe_perturb", P, node_size, node, speed=slowdown)
 
 
 def latency_perturbation(P: int, *, node_size: int = 16, node: int = 1,
-                         delay: float = 10.0) -> Scenario:
+                         delay: float = 10.0) -> ClusterSpec:
     """+``delay`` seconds per message to/from every PE of one node."""
-    profiles = [PEProfile() for _ in range(P)]
-    for pe in range(node * node_size, min(P, (node + 1) * node_size)):
-        profiles[pe].msg_latency = delay
-    return Scenario("latency_perturb", profiles)
+    return _node("latency_perturb", P, node_size, node, msg_latency=delay)
 
 
 def combined_perturbation(P: int, *, node_size: int = 16, node: int = 1,
                           slowdown: float = 0.25,
-                          delay: float = 10.0) -> Scenario:
-    profiles = [PEProfile() for _ in range(P)]
-    for pe in range(node * node_size, min(P, (node + 1) * node_size)):
-        profiles[pe].speed = slowdown
-        profiles[pe].msg_latency = delay
-    return Scenario("combined_perturb", profiles)
+                          delay: float = 10.0) -> ClusterSpec:
+    return _node("combined_perturb", P, node_size, node, speed=slowdown,
+                 msg_latency=delay)
 
 
 def paper_scenarios(P: int, *, t_exec_estimate: float,
-                    seed: int = 0) -> dict[str, Scenario]:
+                    seed: int = 0) -> dict[str, ClusterSpec]:
     """The seven execution scenarios of Table 1."""
     return {
         "baseline": baseline(P),

@@ -11,10 +11,10 @@ same frozen, composable :class:`RunSpec`:
       ├── SchedulingSpec   which DLS technique sizes chunks (+ its params)
       ├── RobustnessSpec   the rDLB knobs (re-issue on/off, duplicate caps)
       ├── ClusterSpec      the workers and their perturbations — the ONE
-      │                    perturbation vocabulary: ``faults.Scenario``,
-      │                    executor ``FaultPlan``s and serve-side
-      │                    dead/slow sets all map onto it, and it is the
-      │                    only constructor of ``EngineWorker`` lists
+      │                    perturbation vocabulary (one WorkerSpec per
+      │                    worker; the paper's Table-1 scenarios are
+      │                    ClusterSpecs, ``repro.api.scenarios``), and
+      │                    the only constructor of ``EngineWorker`` lists
       ├── ExecutionSpec    virtual-time vs threaded, h, horizon, polling
       └── AdaptiveSpec     simulate-in-the-loop re-planning cadence/knobs
 
@@ -126,11 +126,12 @@ class RobustnessSpec:
 class WorkerSpec:
     """One worker's perturbation profile — THE unified vocabulary.
 
-    Absorbs all three legacy spellings: ``faults.PEProfile`` (speed /
-    msg_latency / fail_time), executor ``FaultPlan`` entries (speed /
-    fail_after_tasks), and serve-side dead/slow sets (alive /
-    sleep_per_task).  ``sleep_per_task`` only matters in threaded mode
-    (an injected wall-clock delay); virtual time uses ``speed``.
+    ``speed`` is relative compute speed (1.0 nominal), ``msg_latency``
+    extra seconds per message to/from the worker, ``fail_time`` a
+    fail-stop instant and ``fail_after_tasks`` a fail-stop after that
+    many task executions; ``alive=False`` is dead from the start.
+    ``sleep_per_task`` only matters in threaded mode (an injected
+    wall-clock delay); virtual time uses ``speed``.
 
     ``hang_time`` is a FREEZE instant (paper Fig. 1b): from the
     scheduler's point of view it is indistinguishable from a fail-stop
@@ -186,28 +187,6 @@ class ClusterSpec:
     @classmethod
     def uniform(cls, n_workers: int, name: str = "") -> "ClusterSpec":
         return cls(n_workers=n_workers, name=name)
-
-    @classmethod
-    def from_scenario(cls, scenario) -> "ClusterSpec":
-        """Absorb a ``faults.Scenario`` (paper Table-1 vocabulary)."""
-        return cls(
-            n_workers=scenario.P, name=scenario.name,
-            workers=tuple(WorkerSpec(speed=p.speed,
-                                     msg_latency=p.msg_latency,
-                                     fail_time=p.fail_time)
-                          for p in scenario.profiles))
-
-    @classmethod
-    def from_fault_plan(cls, n_workers: int, plan=None,
-                        name: str = "fault_plan") -> "ClusterSpec":
-        """Absorb a training-executor ``FaultPlan`` (fail_after / slow)."""
-        fail_after = dict(getattr(plan, "fail_after", None) or {})
-        slow = dict(getattr(plan, "slow", None) or {})
-        return cls(
-            n_workers=n_workers, name=name,
-            workers=tuple(WorkerSpec(speed=slow.get(w, 1.0),
-                                     fail_after_tasks=fail_after.get(w))
-                          for w in range(n_workers)))
 
     @classmethod
     def from_worker_states(cls, states: Sequence,

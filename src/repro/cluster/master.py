@@ -79,7 +79,7 @@ def factory_for_backend(backend: Any) -> Any:
     from repro.core.simulator import SimBackend
     from repro.runtime.backends import FnBackend
     if isinstance(backend, SimBackend):
-        return SleepRunner(task_times=np.diff(backend._ctime))
+        return SleepRunner(task_times=np.diff(backend.ctime))
     if isinstance(backend, FnBackend):
         tt = (np.diff(backend._ctime) if backend._ctime is not None
               else None)

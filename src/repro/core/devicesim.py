@@ -51,6 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.core import dls
+
 _BIG = np.int32(2 ** 30)        # "no chunk" sentinel in seq space
 _NEVER = np.float64(np.inf)     # "never fails"
 
@@ -84,8 +86,6 @@ def lower_run(spec, task_times, *,
     fail-stop DRAWS are allowed (they batch as the perturbation axis),
     heterogeneity/adaptivity/barriers/finite dup caps are not.
     """
-    from repro import api   # lazy: api imports core
-
     if spec.execution.mode != "virtual":
         return None, f"mode={spec.execution.mode!r} (need virtual)"
     if spec.adaptive.enabled:
@@ -119,7 +119,9 @@ def lower_run(spec, task_times, *,
             fail[i] = min(stops)
     tech = technique
     if tech is None:
-        tech = api.make_scheduler(spec, N)
+        sched = spec.scheduling
+        tech = dls.make_technique(sched.technique, N, P, seed=sched.seed,
+                                  **sched.param_dict())
     if getattr(tech, "barrier_per_batch", False):
         return None, f"{tech.name}: batch-weight barrier technique"
     c = tech.fixed_chunk()

@@ -2,7 +2,7 @@ from repro.runtime.backends import (  # noqa: F401
     ChunkBackend, FnBackend, ServeBackend, TrainBackend,
 )
 from repro.runtime.executor import (  # noqa: F401
-    FaultPlan, RDLBTrainExecutor, StepResult, WorkerState,
+    RDLBTrainExecutor, StepResult, WorkerState,
 )
 from repro.runtime.serve_executor import (  # noqa: F401
     RDLBServeExecutor, Request, ServeStats,

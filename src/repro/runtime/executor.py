@@ -22,12 +22,10 @@ reduction):
     surfaced as ``StepResult.hung`` instead of an infinite wait.
 
 Configuration is a declarative :class:`repro.api.RunSpec`
-(``RDLBTrainExecutor(model, spec=spec)``); the legacy keyword vocabulary
-(``technique=``, ``rdlb_enabled=``, ``FaultPlan`` …) still works as a
-shim that builds the equivalent spec under a ``DeprecationWarning``.
-Worker perturbations — spec-declared or FaultPlan-injected — flow through
-the ONE vocabulary, ``repro.api.ClusterSpec``, which is the only
-constructor of ``EngineWorker`` lists.
+(``RDLBTrainExecutor(model, spec=spec)``, built with ``api.train_spec``).
+Worker perturbations (stragglers via ``speed``, count-based fail-stops
+via ``fail_after_tasks``, ...) are declared on ``spec.cluster``, the ONE
+vocabulary and the only constructor of ``EngineWorker`` lists.
 
 After a step with losses, ``runtime.elastic`` shrinks the worker set (and,
 on hardware, re-meshes + re-shards via the checkpoint substrate).
@@ -45,8 +43,6 @@ from repro.data import chunk_batch
 from repro.optim import apply_updates, clip_by_global_norm, make_optimizer
 from repro.runtime.backends import TrainBackend
 
-_UNSET = object()
-
 
 @dataclasses.dataclass
 class WorkerState:
@@ -61,25 +57,6 @@ class WorkerState:
     # (fail_time, msg_latency, sleep_per_task) back into each step's
     # ClusterSpec.  None = nominal.
     profile: Optional[api.WorkerSpec] = None
-
-
-@dataclasses.dataclass
-class FaultPlan:
-    """Per-step fault/perturbation injection (worker id -> behaviour).
-
-    Legacy vocabulary: ``ClusterSpec.from_fault_plan`` absorbs it into
-    the unified WorkerSpec fields (``slow`` maps to ``speed``,
-    ``fail_after`` to ``fail_after_tasks``).
-    """
-    fail_after: dict = dataclasses.field(default_factory=dict)
-    slow: dict = dataclasses.field(default_factory=dict)
-
-    def apply(self, workers: list[WorkerState]) -> None:
-        for w in workers:
-            if w.wid in self.fail_after:
-                w.fail_after_tasks = self.fail_after[w.wid]
-            if w.wid in self.slow:
-                w.speed = self.slow[w.wid]
 
 
 @dataclasses.dataclass
@@ -115,38 +92,13 @@ class RDLBTrainExecutor:
     adaptive:    optional live adaptive policy object
                  (repro.adaptive.AdaptiveController), overriding
                  ``spec.adaptive``.
-
-    Legacy keywords (deprecated): ``n_workers``, ``n_tasks``,
-    ``technique``, ``rdlb_enabled``, ``max_duplicates``, ``concurrent``
-    build the equivalent spec and warn.
     """
 
-    def __init__(self, model, *, spec: Optional[api.RunSpec] = None,
-                 n_workers: Any = _UNSET, n_tasks: Any = _UNSET,
-                 technique: Any = _UNSET, rdlb_enabled: Any = _UNSET,
+    def __init__(self, model, *, spec: api.RunSpec,
                  optimizer: str = "adamw", lr: float = 1e-3,
                  grad_clip: float = 1.0, exact_accumulation: bool = False,
-                 max_duplicates: Any = _UNSET,
                  loss_fn: Optional[Callable] = None,
-                 concurrent: Any = _UNSET,
                  adaptive: Optional[Any] = None):
-        legacy = {k: v for k, v in dict(
-            n_workers=n_workers, n_tasks=n_tasks, technique=technique,
-            rdlb_enabled=rdlb_enabled, max_duplicates=max_duplicates,
-            concurrent=concurrent).items() if v is not _UNSET}
-        if spec is None:
-            if legacy:
-                api.warn_legacy(f"RDLBTrainExecutor({', '.join(legacy)})")
-            spec = api.train_spec(
-                technique=legacy.get("technique", "FAC"),
-                n_workers=legacy.get("n_workers", 4),
-                n_tasks=legacy.get("n_tasks", 8),
-                rdlb_enabled=legacy.get("rdlb_enabled", True),
-                max_duplicates=legacy.get("max_duplicates"),
-                threaded=bool(legacy.get("concurrent")))
-        elif legacy:
-            raise TypeError("pass spec= OR legacy keywords, not both: "
-                            f"{sorted(legacy)}")
         if spec.n_tasks is None:
             raise ValueError("training needs spec.n_tasks (microbatches "
                              "per global step)")
@@ -182,16 +134,11 @@ class RDLBTrainExecutor:
 
     # ---------------------------------------------------------------- step
     def train_step(self, params, opt_state, batch: dict, *,
-                   fault_plan: Optional[FaultPlan] = None,
                    max_rounds: Optional[int] = None) -> StepResult:
         B = batch["tokens"].shape[0]
         assert B % self.n_tasks == 0, (B, self.n_tasks)
-        if fault_plan:
-            api.warn_legacy("train_step(fault_plan=...); declare the "
-                            "perturbations on spec.cluster")
-            fault_plan.apply(self.workers)
         # The step's cluster is the LIVE worker state (liveness and
-        # speeds learned/injected so far), through the one vocabulary.
+        # speeds learned so far), through the one vocabulary.
         cluster = api.ClusterSpec.from_worker_states(
             self.workers, name=self.spec.cluster.name or "train")
         spec = self.spec.replace(cluster=cluster, n_tasks=self.n_tasks)

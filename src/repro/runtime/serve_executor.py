@@ -23,10 +23,15 @@ Three performance layers on top of the shared engine:
     (decode_step + on-device argmax + token feedback) steps inside a
     ``lax.scan`` with the cache donated between steps.  Zero host
     round-trips per token; token-identical to the loop.
-  * CONCURRENT MODE (``concurrent=True``): replicas run as real OS
-    threads; rDLB duplicates genuinely race their originals in wall-clock
-    time, and first-completion-wins is physical rather than an artifact
-    of round-robin ordering.
+  * CONCURRENT MODE (``execution.mode="threaded"`` on the spec, or
+    ``serve(concurrent=True)``): replicas run as real OS threads; rDLB
+    duplicates genuinely race their originals in wall-clock time, and
+    first-completion-wins is physical rather than an artifact of
+    round-robin ordering.
+
+Configuration is a declarative :class:`repro.api.RunSpec`
+(``RDLBServeExecutor(model, params, spec=spec)``, built with
+``api.serve_spec``); replica perturbations are declared on its cluster.
 """
 
 from __future__ import annotations
@@ -46,8 +51,6 @@ from repro.runtime.backends import ServeBackend
 # on TPU the same donate_argnums reuses the cache buffers in place.
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
-
-_UNSET = object()
 
 
 @dataclasses.dataclass
@@ -211,35 +214,15 @@ class RDLBServeExecutor:
     The spec's cluster is the one perturbation vocabulary: declare dead
     replicas (``alive=False``), stragglers (``sleep_per_task`` /
     ``speed``) or count-based fail-stops (``fail_after_tasks``) there.
-    Legacy keywords (``n_workers=``, ``technique=``, …) and the mutable
-    ``dead``/``slow`` sets still work as a deprecation shim — both paths
-    meet in ``ClusterSpec.with_serve_state``.
+    The live ``dead``/``slow`` sets (observed replica deaths persist
+    across ``serve()`` calls) overlay it each call, through
+    ``ClusterSpec.with_serve_state``.
     """
 
-    def __init__(self, model, params, *, spec: Optional[api.RunSpec] = None,
-                 n_workers: Any = _UNSET,
-                 technique: Any = _UNSET, rdlb_enabled: Any = _UNSET,
-                 max_duplicates: Any = _UNSET,
+    def __init__(self, model, params, *, spec: api.RunSpec,
                  batch_decode: bool = True,
                  fused_decode: bool = True,
-                 concurrent: Any = _UNSET,
                  adaptive: Optional[Any] = None):
-        legacy = {k: v for k, v in dict(
-            n_workers=n_workers, technique=technique,
-            rdlb_enabled=rdlb_enabled, max_duplicates=max_duplicates,
-            concurrent=concurrent).items() if v is not _UNSET}
-        if spec is None:
-            if legacy:
-                api.warn_legacy(f"RDLBServeExecutor({', '.join(legacy)})")
-            spec = api.serve_spec(
-                technique=legacy.get("technique", "SS"),
-                n_workers=legacy.get("n_workers", 2),
-                rdlb_enabled=legacy.get("rdlb_enabled", True),
-                max_duplicates=legacy.get("max_duplicates"),
-                threaded=bool(legacy.get("concurrent")))
-        elif legacy:
-            raise TypeError("pass spec= OR legacy keywords, not both: "
-                            f"{sorted(legacy)}")
         self.spec = spec
         self.model = model
         self.params = params
@@ -252,8 +235,8 @@ class RDLBServeExecutor:
         # on TPU instead of copying the full KV/state cache per token
         self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
         self._fused = FusedGenerator(model) if fused_decode else None
-        # Live perturbation state the legacy vocabulary mutates between
-        # serve() calls; overlaid on the spec's cluster each serve().
+        # Live perturbation state, mutated between serve() calls;
+        # overlaid on the spec's cluster each serve().
         # Spec-declared deaths seed the set so fail-stops persist.
         self.dead: set[int] = {wid for wid, w in
                                enumerate(spec.cluster.worker_specs())

@@ -11,10 +11,12 @@ import math
 import numpy as np
 import pytest
 
+from repro import api
 from repro.adaptive import (AdaptiveConfig, AdaptiveController, Candidate,
                             capture, coarsen_times, forecast_candidate,
                             run_adaptive, run_static, sweep)
-from repro.core import dls, engine, faults, rdlb, simulator
+from repro.api import scenarios
+from repro.core import dls, engine, rdlb, simulator
 
 P_SMALL, N_SMALL = 4, 96
 PORTFOLIO = tuple(Candidate(t) for t in ("FAC", "GSS", "mFSC", "AWF-C",
@@ -26,13 +28,18 @@ def task_times(n, seed=0, mean=0.01, sd=0.004):
     return np.abs(rng.normal(mean, sd, n)) + 1e-4
 
 
+def cluster(*workers, name=""):
+    return api.ClusterSpec(n_workers=len(workers), workers=workers,
+                           name=name)
+
+
 def perturb_scenario():
-    return faults.Scenario("mix", [
-        faults.PEProfile(),
-        faults.PEProfile(speed=0.25),
-        faults.PEProfile(msg_latency=0.05),
-        faults.PEProfile(),
-    ])
+    return cluster(
+        api.WorkerSpec(),
+        api.WorkerSpec(speed=0.25),
+        api.WorkerSpec(msg_latency=0.05),
+        api.WorkerSpec(),
+        name="mix")
 
 
 class CaptureAt:
@@ -97,11 +104,11 @@ def run_engine(policy, *, threaded=False, scenario=None, n=N_SMALL,
                technique="FAC", tt=None):
     sc = scenario or perturb_scenario()
     tt = task_times(n) if tt is None else tt
-    tech = dls.make_technique(technique, n, sc.P, seed=1)
+    tech = dls.make_technique(technique, n, sc.n_workers, seed=1)
     queue = rdlb.RobustQueue(n, tech)
     backend = CountingBackend(tt)
-    eng = engine.Engine(queue, simulator.workers_from_scenario(sc),
-                        backend, h=1e-4, adaptive=policy)
+    eng = engine.Engine(queue, sc.engine_workers(), backend, h=1e-4,
+                        adaptive=policy)
     st = eng.run_threaded() if threaded else eng.run()
     return st, queue, backend
 
@@ -130,9 +137,8 @@ def test_snapshot_capture_midrun():
 
 
 def test_snapshot_excludes_future_failures():
-    sc = faults.Scenario("late_fail", [
-        faults.PEProfile(), faults.PEProfile(fail_time=1e9),
-    ])
+    sc = cluster(api.WorkerSpec(), api.WorkerSpec(fail_time=1e9),
+                 name="late_fail")
     policy = CaptureAt(after_reports=2)
     run_engine(policy, scenario=sc, n=16)
     snap = policy.snap
@@ -157,12 +163,14 @@ def test_forecast_resume_matches_fresh_simulation(cand):
 
     # fresh simulation of the remainder, built by hand from the snapshot
     rem_times = tt[np.array(snap.remaining)]
-    profiles = [faults.PEProfile(speed=w.speed, msg_latency=w.msg_latency)
-                for w in snap.workers if w.alive]
-    fresh_sc = faults.Scenario("fresh", profiles)
-    tech = dls.make_technique(cand.technique, len(rem_times), fresh_sc.P,
-                              seed=0, h=h)
-    fresh = simulator.simulate(rem_times, tech, fresh_sc, h=h)
+    fresh_sc = cluster(*(api.WorkerSpec(speed=w.speed,
+                                        msg_latency=w.msg_latency)
+                         for w in snap.workers if w.alive), name="fresh")
+    tech = dls.make_technique(cand.technique, len(rem_times),
+                              fresh_sc.n_workers, seed=0, h=h)
+    fresh = api.simulate(api.RunSpec(cluster=fresh_sc,
+                                     execution=api.ExecutionSpec(h=h)),
+                         rem_times, technique=tech)
     assert predicted == fresh.t_par
 
 
@@ -203,13 +211,13 @@ def test_hot_swap_exactly_once_threaded():
     """Same invariant under real OS-thread concurrency, with a straggler
     and a count-based fail-stop racing the swap."""
     n = 48
-    sc = faults.Scenario("threaded", [faults.PEProfile()] * 3)
+    sc = scenarios.baseline(3)
     policy = SwapAt(after_reports=3, technique="GSS")
     tt = task_times(n)
     tech = dls.make_technique("SS", n, 3, seed=1)
     queue = rdlb.RobustQueue(n, tech)
     backend = CountingBackend(tt)
-    workers = simulator.workers_from_scenario(sc)
+    workers = sc.engine_workers()
     workers[0].sleep_per_task = 0.002          # straggler
     workers[2].fail_after_tasks = 5            # dies holding a chunk
     eng = engine.Engine(queue, workers, backend, h=0.0, adaptive=policy)
@@ -254,7 +262,7 @@ def test_swap_technique_defaults_keep_knobs():
 # ------------------------------------------------------------- controller
 def test_controller_records_decisions_and_completes():
     tt = task_times(256)
-    sc = faults.pe_perturbation(8, node_size=4)    # workers 4..7 slowed
+    sc = scenarios.pe_perturbation(8, node_size=4)  # workers 4..7 slowed
     cfg = AdaptiveConfig(portfolio=PORTFOLIO, decision_every_chunks=16,
                          min_remaining=16, max_sim_tasks=None)
     res, ctrl = run_adaptive(tt, sc, initial="FAC", config=cfg)
@@ -269,13 +277,14 @@ def test_controller_swaps_away_from_bad_initial():
     """Start from SS with a large master overhead: every forecast sees
     SS's serialization cost and the t=0 plan must swap off it."""
     tt = np.full(512, 0.001)
-    sc = faults.baseline(8)
+    sc = scenarios.baseline(8)
     cfg = AdaptiveConfig(portfolio=(Candidate("FAC"),),
                          decision_every_chunks=None, max_sim_tasks=None,
                          hysteresis=0.05)
     ctrl = AdaptiveController(task_times=tt, config=cfg)
     tech = dls.make_technique("SS", 512, 8, h=5e-3)
-    res = simulator.simulate(tt, tech, sc, h=5e-3, adaptive=ctrl)
+    spec = api.RunSpec(cluster=sc, execution=api.ExecutionSpec(h=5e-3))
+    res = api.simulate(spec, tt, technique=tech, adaptive=ctrl)
     assert ctrl.decisions[0].swapped
     assert ctrl.decisions[0].chosen == "FAC"
     ss = run_static(tt, sc, Candidate("SS"), h=5e-3).t_par
@@ -284,12 +293,12 @@ def test_controller_swaps_away_from_bad_initial():
 
 def test_controller_reusable_across_runs():
     tt = task_times(128)
-    sc = faults.baseline(4)
+    spec = api.RunSpec(cluster=scenarios.baseline(4))
     cfg = AdaptiveConfig(portfolio=PORTFOLIO[:2], max_sim_tasks=None)
     ctrl = AdaptiveController(task_times=tt, config=cfg)
     for _ in range(2):
         tech = dls.make_technique("FAC", 128, 4)
-        r = simulator.simulate(tt, tech, sc, adaptive=ctrl)
+        r = api.simulate(spec, tt, technique=tech, adaptive=ctrl)
         assert not r.hang
     assert len(ctrl.decisions) >= 1             # re-bound, not accumulated
 
@@ -300,18 +309,18 @@ def test_stats_surface_decisions():
     ctrl = AdaptiveController(task_times=tt, config=cfg)
     tech = dls.make_technique("FAC", 128, 4)
     queue = rdlb.RobustQueue(128, tech)
-    eng = engine.Engine(queue, simulator.workers_from_scenario(
-        faults.baseline(4)), simulator.SimBackend(tt), adaptive=ctrl)
+    eng = engine.Engine(queue, scenarios.baseline(4).engine_workers(),
+                        simulator.SimBackend(tt), adaptive=ctrl)
     st = eng.run()
     assert st.adaptive_decisions == ctrl.decisions
 
 
 # ------------------------------------------------- acceptance criterion
 @pytest.mark.parametrize("scenario_fn", [
-    lambda P: faults.pe_perturbation(P, node_size=8),
-    lambda P: faults.latency_perturbation(P, node_size=8, delay=0.5),
-    lambda P: faults.combined_perturbation(P, node_size=8,
-                                           slowdown=0.25, delay=0.5),
+    lambda P: scenarios.pe_perturbation(P, node_size=8),
+    lambda P: scenarios.latency_perturbation(P, node_size=8, delay=0.5),
+    lambda P: scenarios.combined_perturbation(P, node_size=8,
+                                              slowdown=0.25, delay=0.5),
 ])
 def test_adaptive_within_15pct_of_oracle(scenario_fn):
     """ISSUE acceptance: under the Table-1 perturbation scenarios, the
@@ -338,8 +347,8 @@ def test_forecast_sweep_is_bounded_by_coarsening():
     tt = task_times(4096)
     tech = dls.make_technique("FAC", 4096, 16)
     queue = rdlb.RobustQueue(4096, tech)
-    eng = engine.Engine(queue, simulator.workers_from_scenario(
-        faults.baseline(16)), simulator.SimBackend(tt))
+    eng = engine.Engine(queue, scenarios.baseline(16).engine_workers(),
+                        simulator.SimBackend(tt))
     snap = capture(eng, 0.0)
     preds = sweep(snap, tt, PORTFOLIO[:3], max_sim_tasks=256)
     assert len(preds) == 3
@@ -369,7 +378,8 @@ def test_executors_accept_adaptive_policy():
     acfg = AdaptiveConfig(portfolio=(Candidate("FAC"), Candidate("GSS")),
                           min_remaining=1, max_sim_tasks=None)
     ctrl = AdaptiveController(config=acfg)       # unit-cost tasks
-    ex = RDLBTrainExecutor(model, n_workers=2, n_tasks=4,
+    ex = RDLBTrainExecutor(model, spec=api.train_spec(n_workers=2,
+                                                      n_tasks=4),
                            exact_accumulation=True, adaptive=ctrl)
     batch = batch_for_step(cfg_m, 0, 8, 16)
     opt_state = ex.opt.init(params)
@@ -381,7 +391,7 @@ def test_executors_accept_adaptive_policy():
     reqs = [Request(i, rng.integers(0, 64, size=4).astype(np.int32),
                     max_new_tokens=2) for i in range(6)]
     ctrl2 = AdaptiveController(config=acfg)
-    sx = RDLBServeExecutor(model, params, n_workers=2, technique="SS",
+    sx = RDLBServeExecutor(model, params, spec=api.serve_spec(n_workers=2),
                            adaptive=ctrl2)
     stats = sx.serve(reqs)
     assert not stats.hung

@@ -4,7 +4,12 @@ without rDLB."""
 
 import numpy as np
 
-from repro.core import dls, faults, rdlb, simulator
+from repro import api
+from repro.api import scenarios
+from repro.core import dls, rdlb
+
+AWF_B = api.RunSpec(scheduling=api.SchedulingSpec(technique="AWF-B"),
+                    cluster=scenarios.baseline(8))
 
 
 def make_q(N=8, P=4, **kw):
@@ -58,9 +63,9 @@ def test_barrier_cap_escalates_no_livelock():
 def test_simulator_awf_pm1_failures_terminates():
     """Regression: P-1 failures + AWF-B barrier used to livelock."""
     tt = np.full(128, 0.01)
-    base = simulator.run(tt, "AWF-B", faults.baseline(8))
-    sc = faults.failures(8, 7, t_exec_estimate=base.t_par, seed=1)
-    r = simulator.run(tt, "AWF-B", sc)
+    base = api.simulate(AWF_B, tt)
+    sc = scenarios.failures(8, 7, t_exec_estimate=base.t_par, seed=1)
+    r = api.simulate(AWF_B.replace(cluster=sc), tt)
     assert not r.hang and r.n_finished == 128
 
 
@@ -68,5 +73,5 @@ def test_simulator_awf_nonrobust_barrier_not_hang_when_healthy():
     """Without failures, AWF-B without rDLB still completes (the barrier
     clears by itself)."""
     tt = np.full(128, 0.01)
-    r = simulator.run(tt, "AWF-B", faults.baseline(8), rdlb_enabled=False)
+    r = api.simulate(AWF_B.override("robustness.rdlb_enabled", False), tt)
     assert not r.hang and r.n_finished == 128

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import api
 from repro.kernels import dispatch, ops, ref
 from repro.models import build_model
 from repro.models.config import ModelConfig
@@ -72,8 +73,8 @@ def test_fused_single_token_generation():
 def _serve(model, params, prompts, new, n_workers=2, **kw):
     reqs = [Request(i, p, max_new_tokens=new)
             for i, p in enumerate(prompts)]
-    ex = RDLBServeExecutor(model, params, n_workers=n_workers,
-                           technique="SS", **kw)
+    ex = RDLBServeExecutor(model, params,
+                           spec=api.serve_spec(n_workers=n_workers), **kw)
     stats = ex.serve(reqs)
     assert not stats.hung
     return [r.output for r in reqs]
@@ -105,7 +106,7 @@ def test_threaded_duplicate_race_token_identical():
                for _ in range(6)]
     reqs = [Request(i, p, max_new_tokens=2)
             for i, p in enumerate(prompts)]
-    ex = RDLBServeExecutor(model, params, n_workers=3, technique="SS")
+    ex = RDLBServeExecutor(model, params, spec=api.serve_spec(n_workers=3))
     stats = ex.serve(reqs, fail_at={1: 1})
     assert not stats.hung
     assert all(r.output is not None for r in reqs)

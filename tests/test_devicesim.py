@@ -20,21 +20,26 @@ import pytest
 
 from repro import api
 from repro.adaptive import capture, sweep
-from repro.api import DEVICE_PORTFOLIO
-from repro.core import devicesim, faults
+from repro.api import DEVICE_PORTFOLIO, scenarios
+from repro.core import devicesim
 
 ATOL = 1e-9
 
 
-def _spec(tech, P, *, rdlb=True, h=1e-4, fails=None, seed=0):
-    sc = faults.baseline(P)
-    if fails:
-        for wid, ft in fails.items():
-            sc.profiles[wid].fail_time = ft
+def fail_stop_cluster(fail_times, name):
+    """One worker per fail time (inf = survives)."""
+    return api.ClusterSpec(
+        n_workers=len(fail_times), name=name,
+        workers=tuple(api.WorkerSpec(
+            fail_time=None if np.isinf(f) else float(f))
+            for f in fail_times))
+
+
+def _spec(tech, P, *, rdlb=True, h=1e-4, seed=0):
     return api.RunSpec(
         scheduling=api.SchedulingSpec(technique=tech, seed=seed),
         robustness=api.RobustnessSpec(rdlb_enabled=rdlb),
-        cluster=api.ClusterSpec.from_scenario(sc),
+        cluster=scenarios.baseline(P),
         execution=api.ExecutionSpec(h=h))
 
 
@@ -44,12 +49,8 @@ def _check(spec, times, fail_times=None):
     assert res is not None, "expected spec to lower"
     assert res.valid.all(), "budget must suffice at test scale"
     if fail_times is not None:
-        prof = [faults.PEProfile(
-                    fail_time=None if np.isinf(f) else float(f))
-                for f in fail_times[0]]
         spec = dataclasses.replace(
-            spec, cluster=api.ClusterSpec.from_scenario(
-                faults.Scenario("draw", prof)))
+            spec, cluster=fail_stop_cluster(fail_times[0], "draw"))
     ref = api.simulate(spec, times)
     assert res.t_par[0] == pytest.approx(ref.t_par, abs=ATOL)
     assert res.n_assignments[0] == ref.n_assignments
@@ -122,12 +123,8 @@ def test_parity_monte_carlo_batch():
     assert res.valid.all()
     for b in range(3 * D):
         t_ix, d = divmod(b, D)
-        prof = [faults.PEProfile(
-                    fail_time=None if np.isinf(f) else float(f))
-                for f in fail[d]]
         sp = dataclasses.replace(
-            specs[t_ix], cluster=api.ClusterSpec.from_scenario(
-                faults.Scenario("x", prof)))
+            specs[t_ix], cluster=fail_stop_cluster(fail[d], "x"))
         ref = api.simulate(sp, times)
         assert res.t_par[b] == pytest.approx(ref.t_par, abs=ATOL), (b,)
         assert res.n_duplicates[b] == ref.n_duplicates
@@ -196,7 +193,7 @@ def test_device_sweep_matches_scalar_sweep():
     tech = dls.make_technique("SS", N, P)
     queue = rdlb.RobustQueue(N, tech)
     eng = engine.Engine(
-        queue, simulator.workers_from_scenario(faults.baseline(P)),
+        queue, scenarios.baseline(P).engine_workers(),
         simulator.SimBackend(tt))
     snap = capture(eng, 0.0)
     scalar = sweep(snap, tt, DEVICE_PORTFOLIO, device=False)

@@ -10,7 +10,9 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import dls, engine, faults, rdlb, simulator
+from repro import api
+from repro.api import scenarios
+from repro.core import dls, engine, rdlb, simulator
 from repro.data import batch_for_step
 from repro.models import build_model
 from repro.models.config import ModelConfig
@@ -34,18 +36,18 @@ def test_sim_and_exec_backends_identical_schedule(technique):
     straggler + fail-stop scenario."""
     N, P = 64, 4
     tt = np.abs(np.random.default_rng(0).normal(0.05, 0.02, N)) + 1e-3
-    sc = faults.Scenario("parity", [
-        faults.PEProfile(),
-        faults.PEProfile(speed=0.25),          # straggler
-        faults.PEProfile(fail_time=0.5),       # fail-stop
-        faults.PEProfile(msg_latency=0.05),    # latency-perturbed
-    ])
+    cluster = api.ClusterSpec(n_workers=P, workers=(
+        api.WorkerSpec(),
+        api.WorkerSpec(speed=0.25),            # straggler
+        api.WorkerSpec(fail_time=0.5),         # fail-stop
+        api.WorkerSpec(msg_latency=0.05),      # latency-perturbed
+    ))
 
     def run_with(backend):
         tech = dls.make_technique(technique, N, P, seed=3)
         queue = rdlb.RobustQueue(N, tech)
-        eng = engine.Engine(queue, simulator.workers_from_scenario(sc),
-                            backend, h=1e-4)
+        eng = engine.Engine(queue, cluster.engine_workers(), backend,
+                            h=1e-4)
         return eng.run()
 
     executed = FnBackend(task_fn=lambda t: t * t, task_times=tt)
@@ -135,9 +137,9 @@ def test_train_step_schedule_invariant(train_setup):
     for kw in (dict(n_workers=1, technique="SS"),
                dict(n_workers=4, technique="FAC"),
                dict(n_workers=3, technique="GSS"),
-               dict(n_workers=4, technique="FAC", concurrent=True)):
-        ex = RDLBTrainExecutor(model, n_tasks=8, exact_accumulation=True,
-                               **kw)
+               dict(n_workers=4, technique="FAC", threaded=True)):
+        ex = RDLBTrainExecutor(model, spec=api.train_spec(n_tasks=8, **kw),
+                               exact_accumulation=True)
         opt_state = ex.opt.init(params)
         res = ex.train_step(params, opt_state, batch)
         assert not res.hung
@@ -169,10 +171,9 @@ def test_batched_decode_matches_per_request(serve_setup):
     a = make_requests(prompts)
     b = make_requests(prompts)
     # GSS -> multi-request chunks -> real batching (and batch-dim padding)
-    RDLBServeExecutor(model, params, n_workers=2, technique="GSS",
-                      batch_decode=True).serve(a)
-    RDLBServeExecutor(model, params, n_workers=2, technique="GSS",
-                      batch_decode=False).serve(b)
+    spec = api.serve_spec(technique="GSS", n_workers=2)
+    RDLBServeExecutor(model, params, spec=spec, batch_decode=True).serve(a)
+    RDLBServeExecutor(model, params, spec=spec, batch_decode=False).serve(b)
     for x, y in zip(a, b):
         assert x.output is not None and np.array_equal(x.output, y.output)
 
@@ -182,10 +183,11 @@ def test_concurrent_serve_first_completion_wins(serve_setup):
     replica still yields complete, deterministic outputs."""
     model, params, prompts = serve_setup
     ref = make_requests(prompts)
-    RDLBServeExecutor(model, params, n_workers=1).serve(ref)
+    RDLBServeExecutor(model, params,
+                      spec=api.serve_spec(n_workers=1)).serve(ref)
     reqs = make_requests(prompts)
-    ex = RDLBServeExecutor(model, params, n_workers=3, technique="SS",
-                           concurrent=True)
+    ex = RDLBServeExecutor(model, params, spec=api.serve_spec(
+        n_workers=3, threaded=True))
     ex.slow[0] = 0.02                        # straggler replica
     stats = ex.serve(reqs, fail_at={1: 1})   # fail-stop replica
     assert not stats.hung
@@ -197,8 +199,8 @@ def test_concurrent_serve_first_completion_wins(serve_setup):
 def test_concurrent_serve_hang_without_rdlb(serve_setup):
     model, params, prompts = serve_setup
     reqs = make_requests(prompts[:4])
-    ex = RDLBServeExecutor(model, params, n_workers=2, technique="SS",
-                           rdlb_enabled=False, concurrent=True)
+    ex = RDLBServeExecutor(model, params, spec=api.serve_spec(
+        n_workers=2, rdlb_enabled=False, threaded=True))
     stats = ex.serve(reqs, fail_at={1: 0})
     assert stats.hung
 
@@ -291,50 +293,48 @@ def test_threaded_commits_exactly_once_under_races(kind):
             np.full(width, sum(range(N)), np.float32))
 
 
-# ---------------------------------------------- spec/legacy parity suite
+# ------------------------------------------- spec vs hand-built parity
 @pytest.mark.parametrize("technique", ["SS", "FAC", "AWF-B"])
-def test_spec_built_run_matches_legacy_assignment_log(technique):
-    """Satellite acceptance: a spec-built run produces an assignment log
-    IDENTICAL to the legacy-kwarg construction of the same run."""
-    from repro import api
+def test_spec_built_run_matches_hand_built_assignment_log(technique):
+    """A spec-built run (api.build) produces an assignment log IDENTICAL
+    to an Engine wired by hand from the same cluster's engine workers."""
     N, P = 64, 4
     tt = np.abs(np.random.default_rng(1).normal(0.05, 0.02, N)) + 1e-3
-    sc = faults.Scenario("parity", [
-        faults.PEProfile(),
-        faults.PEProfile(speed=0.25),
-        faults.PEProfile(fail_time=0.5),
-        faults.PEProfile(msg_latency=0.05),
-    ])
+    cluster = api.ClusterSpec(n_workers=P, workers=(
+        api.WorkerSpec(),
+        api.WorkerSpec(speed=0.25),
+        api.WorkerSpec(fail_time=0.5),
+        api.WorkerSpec(msg_latency=0.05),
+    ))
 
-    # legacy wiring, by hand
+    # wiring by hand
     tech = dls.make_technique(technique, N, P, seed=3)
     queue = rdlb.RobustQueue(N, tech, max_duplicates=2)
-    legacy_eng = engine.Engine(queue, simulator.workers_from_scenario(sc),
-                               simulator.SimBackend(tt), h=1e-4)
-    st_legacy = legacy_eng.run()
+    hand_eng = engine.Engine(queue, cluster.engine_workers(),
+                             simulator.SimBackend(tt), h=1e-4)
+    st_hand = hand_eng.run()
 
     # the same run, declared as data
     spec = api.RunSpec(
         scheduling=api.SchedulingSpec(technique=technique, seed=3),
         robustness=api.RobustnessSpec(max_duplicates=2),
-        cluster=api.ClusterSpec.from_scenario(sc),
+        cluster=cluster,
         execution=api.ExecutionSpec(h=1e-4))
     spec = api.RunSpec.from_json(spec.to_json())   # ... through JSON
     spec_eng = api.build(spec, simulator.SimBackend(tt), n_tasks=N)
     st_spec = api.run(spec, spec_eng)
 
-    assert not st_legacy.hung and not st_spec.hung
-    assert ([chunk_key(c) for c in st_legacy.assignment_log]
+    assert not st_hand.hung and not st_spec.hung
+    assert ([chunk_key(c) for c in st_hand.assignment_log]
             == [chunk_key(c) for c in st_spec.assignment_log])
-    assert st_legacy.t_virtual == pytest.approx(st_spec.t_virtual)
-    assert st_legacy.n_duplicates == st_spec.n_duplicates
+    assert st_hand.t_virtual == pytest.approx(st_spec.t_virtual)
+    assert st_hand.n_duplicates == st_spec.n_duplicates
 
 
 # ------------------------------------- idle accounting (count-based fail)
 def test_idle_clamped_at_last_completion_for_count_based_failstop():
     """Regression: a worker with fail_after_tasks (fail_time None) used
     to accrue idle until t_par; idle now ends at its last completion."""
-    from repro import api
     N = 8
     tt = np.ones(N)
     spec = api.RunSpec(
@@ -381,7 +381,6 @@ def test_threaded_knobs_plumbed_from_spec():
     plumbing broke, this run would last the full 30 s and fail the
     wall-clock bound below."""
     import time
-    from repro import api
     N = 8
     tt = np.full(N, 0.01)
     spec = api.RunSpec(
@@ -406,10 +405,10 @@ def test_threaded_knobs_plumbed_from_spec():
 def test_engine_stats_coherent():
     N, P = 32, 4
     tt = np.full(N, 0.01)
-    sc = faults.failures(P, 1, t_exec_estimate=N * 0.01 / P, seed=0)
+    sc = scenarios.failures(P, 1, t_exec_estimate=N * 0.01 / P, seed=0)
     tech = dls.make_technique("FAC", N, P)
     queue = rdlb.RobustQueue(N, tech)
-    eng = engine.Engine(queue, simulator.workers_from_scenario(sc),
+    eng = engine.Engine(queue, sc.engine_workers(),
                         simulator.SimBackend(tt), h=1e-4)
     st = eng.run()
     assert not st.hung and st.n_finished == N

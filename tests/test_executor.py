@@ -6,11 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import api
 from repro.data import batch_for_step
 from repro.models import build_model
 from repro.models.config import ModelConfig
-from repro.runtime import (FaultPlan, RDLBServeExecutor, RDLBTrainExecutor,
-                           Request)
+from repro.runtime import RDLBServeExecutor, RDLBTrainExecutor, Request
 from repro.runtime.elastic import (rebalance_tasks, shrink_to_survivors)
 
 CFG = ModelConfig(family="dense", n_layers=2, d_model=64, n_heads=4,
@@ -27,12 +27,21 @@ def setup():
 
 def run_step(model, params, batch, *, fault=None, rdlb=True,
              technique="FAC", n_workers=4, n_tasks=8):
-    ex = RDLBTrainExecutor(model, n_workers=n_workers, n_tasks=n_tasks,
-                           technique=technique, rdlb_enabled=rdlb,
-                           exact_accumulation=True)
+    """One train step; ``fault`` maps worker id -> its WorkerSpec on the
+    spec's cluster (the rest nominal)."""
+    fault = fault or {}
+    spec = api.train_spec(technique=technique, n_workers=n_workers,
+                          n_tasks=n_tasks, rdlb_enabled=rdlb)
+    spec = spec.override("cluster.workers", tuple(
+        fault.get(w, api.WorkerSpec()) for w in range(n_workers)))
+    ex = RDLBTrainExecutor(model, spec=spec, exact_accumulation=True)
     opt_state = ex.opt.init(params)
-    res = ex.train_step(params, opt_state, batch, fault_plan=fault)
+    res = ex.train_step(params, opt_state, batch)
     return ex, res
+
+
+def fail_after(tasks):
+    return {w: api.WorkerSpec(fail_after_tasks=n) for w, n in tasks.items()}
 
 
 def trees_equal(a, b):
@@ -56,7 +65,7 @@ def test_grads_identical_under_failures(setup, technique):
     model, params, batch = setup
     _, clean = run_step(model, params, batch, technique=technique)
     _, faulty = run_step(model, params, batch, technique=technique,
-                         fault=FaultPlan(fail_after={1: 1, 3: 0}))
+                         fault=fail_after({1: 1, 3: 0}))
     assert not faulty.hung
     assert faulty.n_duplicates >= 1
     assert trees_equal(clean.params, faulty.params)
@@ -67,7 +76,7 @@ def test_w_minus_1_failures_tolerated(setup):
     model, params, batch = setup
     _, clean = run_step(model, params, batch)
     _, res = run_step(model, params, batch,
-                      fault=FaultPlan(fail_after={1: 0, 2: 0, 3: 0}))
+                      fault=fail_after({1: 0, 2: 0, 3: 0}))
     assert not res.hung and len(res.survivors) == 1
     assert trees_equal(clean.params, res.params)
 
@@ -75,7 +84,7 @@ def test_w_minus_1_failures_tolerated(setup):
 def test_hang_without_rdlb(setup):
     model, params, batch = setup
     _, res = run_step(model, params, batch, rdlb=False,
-                      fault=FaultPlan(fail_after={1: 1}))
+                      fault=fail_after({1: 1}))
     assert res.hung
 
 
@@ -89,11 +98,8 @@ def test_no_failure_no_rdlb_is_fine(setup):
 def test_straggler_gets_duplicated(setup):
     model, params, batch = setup
     _, clean = run_step(model, params, batch)
-    ex = RDLBTrainExecutor(model, n_workers=4, n_tasks=8, technique="SS",
-                           exact_accumulation=True)
-    opt_state = ex.opt.init(params)
-    res = ex.train_step(params, opt_state, batch,
-                        fault_plan=FaultPlan(slow={0: 0.05}))
+    _, res = run_step(model, params, batch, technique="SS",
+                      fault={0: api.WorkerSpec(speed=0.05)})
     assert not res.hung
     assert trees_equal(clean.params, res.params)
 
@@ -101,7 +107,7 @@ def test_straggler_gets_duplicated(setup):
 def test_elastic_shrink_and_rebalance(setup):
     model, params, batch = setup
     ex, res = run_step(model, params, batch,
-                       fault=FaultPlan(fail_after={2: 0}))
+                       fault=fail_after({2: 0}))
     st = shrink_to_survivors(ex)
     assert ex.n_workers == 3 and st.generation == 1
     n = rebalance_tasks(8, ex.n_workers, 16)
@@ -126,12 +132,9 @@ def test_shrink_carries_survivor_state(setup):
     survivors, discarding observed speed and execution history that
     adaptive policies (and AWF-style weights) prime from."""
     model, params, batch = setup
-    ex = RDLBTrainExecutor(model, n_workers=4, n_tasks=8, technique="FAC",
-                           exact_accumulation=True)
-    opt_state = ex.opt.init(params)
-    res = ex.train_step(params, opt_state, batch,
-                        fault_plan=FaultPlan(fail_after={2: 0},
-                                             slow={0: 0.5}))
+    ex, res = run_step(model, params, batch,
+                       fault={0: api.WorkerSpec(speed=0.5),
+                              **fail_after({2: 0})})
     assert not res.hung
     before = {w.wid: (w.speed, w.tasks_done)
               for w in ex.workers if w.alive}
@@ -149,11 +152,8 @@ def test_shrink_carries_survivor_state(setup):
 
 def test_wasted_work_accounting(setup):
     model, params, batch = setup
-    ex = RDLBTrainExecutor(model, n_workers=4, n_tasks=4, technique="SS",
-                           exact_accumulation=True)
-    opt_state = ex.opt.init(params)
-    res = ex.train_step(params, opt_state, batch,
-                        fault_plan=FaultPlan(slow={0: 0.01}))
+    _, res = run_step(model, params, batch, technique="SS", n_tasks=4,
+                      fault={0: api.WorkerSpec(speed=0.01)})
     # duplicates may or may not land first; executed >= n_tasks
     executed = sum(res.tasks_by_worker.values())
     assert executed >= res.n_tasks
@@ -167,13 +167,14 @@ def test_serve_failure_recovery():
     rng = np.random.default_rng(0)
     reqs = [Request(i, rng.integers(0, 64, size=4).astype(np.int32),
                     max_new_tokens=2) for i in range(6)]
-    ex = RDLBServeExecutor(model, params, n_workers=3, technique="SS")
+    ex = RDLBServeExecutor(model, params, spec=api.serve_spec(n_workers=3))
     stats = ex.serve(reqs, fail_at={1: 1})
     assert not stats.hung
     assert all(r.output is not None for r in reqs)
     # deterministic decode: duplicates produce identical tokens, so
     # results are valid regardless of which worker finished them
-    ex2 = RDLBServeExecutor(model, params, n_workers=1, technique="SS")
+    ex2 = RDLBServeExecutor(model, params,
+                            spec=api.serve_spec(n_workers=1))
     reqs2 = [Request(i, reqs[i].prompt, max_new_tokens=2) for i in range(6)]
     ex2.serve(reqs2)
     for a, b in zip(reqs, reqs2):
@@ -186,7 +187,7 @@ def test_serve_hang_without_rdlb():
     params = model.init(jax.random.PRNGKey(0))
     reqs = [Request(i, np.arange(4, dtype=np.int32), max_new_tokens=1)
             for i in range(4)]
-    ex = RDLBServeExecutor(model, params, n_workers=2, technique="SS",
-                           rdlb_enabled=False)
+    ex = RDLBServeExecutor(model, params, spec=api.serve_spec(
+        n_workers=2, rdlb_enabled=False))
     stats = ex.serve(reqs, fail_at={1: 0})
     assert stats.hung

@@ -25,7 +25,7 @@ from _hypothesis_compat import given, settings, st
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from repro.core import dls, engine, faults, rdlb, refqueue, simulator
+from repro.core import dls, engine, rdlb, refqueue, simulator
 
 SCENARIO_KINDS = ("fail_stop", "count_fail_stop", "straggler",
                   "msg_latency")

@@ -1,22 +1,27 @@
 """Spec-layer tests: lossless JSON round-trips over every paper
-scenario, the one-perturbation-vocabulary ClusterSpec constructors,
-Candidate-as-spec-delta, legacy-kwarg deprecation shims, and the
-``python -m repro`` CLI (a fig4 resilience data point from a JSON
-file)."""
+scenario, the Table-1 scenarios as ClusterSpecs, the one-perturbation-
+vocabulary ClusterSpec constructors, Candidate-as-spec-delta, the
+spec-only executor constructors, core's independence of the api layer,
+and the ``python -m repro`` CLI (a fig4 resilience data point from a
+JSON file)."""
 
+import ast
+import dataclasses
 import io
 import json
 import math
 import warnings
 from contextlib import redirect_stdout
+from pathlib import Path
 
 import numpy as np
 import pytest
 
+import repro.core
 from repro import api
 from repro.adaptive import capture, forecast_candidate
-from repro.core import dls, engine, faults, rdlb, simulator
-from repro.runtime.executor import FaultPlan
+from repro.api import scenarios
+from repro.core import dls, engine, rdlb, simulator
 
 
 # ---------------------------------------------------------- round-trips
@@ -26,7 +31,7 @@ def spec_for_scenario(sc):
                                       params=(("h", 1e-3),)),
         robustness=api.RobustnessSpec(max_duplicates=3,
                                       barrier_max_duplicates=None),
-        cluster=api.ClusterSpec.from_scenario(sc),
+        cluster=sc,
         execution=api.ExecutionSpec(mode="threaded", h=1e-3,
                                     horizon=1e6, poll=2e-3),
         adaptive=api.AdaptiveSpec(
@@ -40,7 +45,7 @@ def spec_for_scenario(sc):
 def test_roundtrip_identity_every_paper_scenario():
     """RunSpec -> to_dict -> JSON -> from_dict -> RunSpec is identity
     for every Table-1 scenario (the satellite acceptance)."""
-    for name, sc in faults.paper_scenarios(
+    for name, sc in scenarios.paper_scenarios(
             16, t_exec_estimate=2.0, seed=5).items():
         spec = spec_for_scenario(sc)
         blob = json.dumps(spec.to_dict())
@@ -64,7 +69,7 @@ def test_roundtrip_preserves_inf_and_none():
 
 
 def test_save_load(tmp_path):
-    spec = spec_for_scenario(faults.baseline(4))
+    spec = spec_for_scenario(scenarios.baseline(4))
     path = tmp_path / "spec.json"
     spec.save(path)
     assert api.RunSpec.load(path) == spec
@@ -90,28 +95,66 @@ def test_override_paths_and_validation():
 
 
 # ------------------------------------------- one perturbation vocabulary
-def test_cluster_from_scenario_matches_engine_workers():
-    sc = faults.Scenario("mix", [
-        faults.PEProfile(),
-        faults.PEProfile(speed=0.25),
-        faults.PEProfile(fail_time=0.5),
-        faults.PEProfile(msg_latency=0.05),
-    ])
-    ws = api.ClusterSpec.from_scenario(sc).engine_workers()
-    assert [w.wid for w in ws] == [0, 1, 2, 3]
-    assert ws[1].speed == 0.25
-    assert ws[2].fail_time == 0.5
-    assert ws[3].msg_latency == 0.05
+TABLE1_P, TABLE1_T = 48, 2.0
+# scenario -> (fail-stops, node 1's speed, node 1's msg_latency)
+TABLE1 = {
+    "baseline": (0, 1.0, 0.0),
+    "fail_1": (1, 1.0, 0.0),
+    "fail_half": (TABLE1_P // 2, 1.0, 0.0),
+    "fail_pm1": (TABLE1_P - 1, 1.0, 0.0),
+    "pe_perturb": (0, 0.25, 0.0),
+    "latency_perturb": (0, 1.0, 10.0),
+    "combined_perturb": (0, 0.25, 10.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TABLE1))
+def test_table1_scenario_is_cluster_spec(name):
+    """Each Table-1 scenario is a ClusterSpec (named after it; the
+    failure scenarios ``fail_<n>``) with one WorkerSpec per worker:
+    victims die inside the run's window and never the master; node 1
+    (PEs 16..31) alone carries the perturbation; the spec round-trips
+    through JSON and drives the engine workers."""
+    n_fail, speed, latency = TABLE1[name]
+    sc = scenarios.paper_scenarios(TABLE1_P, t_exec_estimate=TABLE1_T,
+                                   seed=3)[name]
+    assert isinstance(sc, api.ClusterSpec)
+    assert sc.name == (f"fail_{n_fail}" if n_fail else name)
+    assert sc.n_workers == len(sc.workers) == TABLE1_P
+    fail = [w.fail_time for w in sc.workers if w.fail_time is not None]
+    assert len(fail) == n_fail
+    assert all(0.05 * TABLE1_T <= t <= 0.95 * TABLE1_T for t in fail)
+    assert sc.workers[0].fail_time is None               # master survives
+    node1 = range(16, 32)
+    for wid, w in enumerate(sc.workers):
+        assert w.speed == (speed if wid in node1 else 1.0), wid
+        assert w.msg_latency == (latency if wid in node1 else 0.0), wid
+    blob = json.dumps(dataclasses.asdict(sc))
+    assert api.ClusterSpec.from_dict(json.loads(blob)) == sc
+    ws = sc.engine_workers()
+    assert [w.wid for w in ws] == list(range(TABLE1_P))
     assert all(w.alive for w in ws)
+    assert [w.fail_time for w in ws] == [w.fail_time for w in sc.workers]
+    assert ws[16].speed == speed and ws[16].msg_latency == latency
 
 
-def test_cluster_from_fault_plan():
-    plan = FaultPlan(fail_after={1: 2, 3: 0}, slow={0: 0.5})
-    ws = api.ClusterSpec.from_fault_plan(4, plan).engine_workers()
-    assert ws[0].speed == 0.5
-    assert ws[1].fail_after_tasks == 2
-    assert ws[3].fail_after_tasks == 0
-    assert ws[2].speed == 1.0 and ws[2].fail_after_tasks is None
+def test_core_imports_no_api():
+    """The layering holds: no module under repro/core imports repro.api
+    (the API layer builds on core, never the reverse)."""
+    offenders = []
+    for path in sorted(Path(repro.core.__file__).parent.glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and node.module:
+                names = [node.module] + [f"{node.module}.{a.name}"
+                                         for a in node.names]
+            else:
+                continue
+            if any(n == "repro.api" or n.startswith("repro.api.")
+                   for n in names):
+                offenders.append(f"{path.name}:{node.lineno}")
+    assert not offenders, offenders
 
 
 def test_cluster_from_serve_maps_both_modes():
@@ -157,8 +200,8 @@ def test_swap_technique_can_toggle_rdlb():
 
 
 def test_spec_declared_cluster_drives_executors():
-    """Perturbations declared ON THE SPEC (not injected via FaultPlan /
-    dead sets) reach the engine workers."""
+    """Perturbations declared ON THE SPEC's cluster reach the engine
+    workers."""
     spec = api.RunSpec(
         cluster=api.ClusterSpec(
             n_workers=3,
@@ -187,9 +230,9 @@ def test_from_worker_states_keeps_spec_profile():
 
 
 def test_train_executor_honors_spec_declared_faults():
-    """A spec ported from the simulator vocabulary (fail-stops declared
-    on the cluster, no FaultPlan anywhere) injects real failures — and
-    the update is still exactly-once-identical to a clean run."""
+    """Fail-stops and stragglers declared on the spec's cluster inject
+    real failures into training — and the update is still
+    exactly-once-identical to a clean run."""
     pytest.importorskip("jax")
     import jax
     from repro.data import batch_for_step
@@ -275,8 +318,8 @@ def test_forecast_sweep_explores_non_dup_fields():
     tt = np.full(128, 0.01)
     tech = dls.make_technique("SS", 128, 4)
     queue = rdlb.RobustQueue(128, tech)
-    eng = engine.Engine(queue, simulator.workers_from_scenario(
-        faults.baseline(4)), simulator.SimBackend(tt), h=1e-4)
+    eng = engine.Engine(queue, scenarios.baseline(4).engine_workers(),
+                        simulator.SimBackend(tt), h=1e-4)
     snap = capture(eng, 0.0)
     lo = forecast_candidate(snap, tt, api.Candidate("SS"), h=1e-4)
     hi = forecast_candidate(
@@ -287,40 +330,14 @@ def test_forecast_sweep_explores_non_dup_fields():
 
 
 # --------------------------------------------------- deprecation shims
-def test_simulate_legacy_kwargs_warn_and_match_spec():
-    """The satellite acceptance: legacy kwargs still work, warn, and are
-    spec-equivalent."""
-    tt = np.abs(np.random.default_rng(0).normal(0.05, 0.02, 64)) + 1e-3
-    sc = faults.Scenario("mix", [
-        faults.PEProfile(),
-        faults.PEProfile(speed=0.25),
-        faults.PEProfile(fail_time=0.5),
-        faults.PEProfile(msg_latency=0.05),
-    ])
-    with pytest.warns(DeprecationWarning, match="legacy keyword API"):
-        legacy = simulator.simulate(
-            tt, dls.make_technique("FAC", 64, 4, seed=3), sc,
-            max_duplicates=2, h=1e-4)
-    spec = api.RunSpec(
-        scheduling=api.SchedulingSpec(technique="FAC", seed=3),
-        robustness=api.RobustnessSpec(max_duplicates=2),
-        cluster=api.ClusterSpec.from_scenario(sc),
-        execution=api.ExecutionSpec(h=1e-4))
-    via_spec = simulator.simulate(tt, spec=spec)
-    assert legacy.t_par == via_spec.t_par
-    assert legacy.n_duplicates == via_spec.n_duplicates
-    assert legacy.wasted_tasks == via_spec.wasted_tasks
-    np.testing.assert_array_equal(legacy.pe_busy, via_spec.pe_busy)
-
-
 def test_simulate_legacy_accepts_custom_technique_objects():
-    """The shim must not reject prebuilt Technique subclasses with
-    unregistered names (queue_cls/custom wiring is a supported seam)."""
+    """api.simulate drives a prebuilt Technique subclass with an
+    unregistered name (``technique=``; custom wiring is a supported
+    seam)."""
     class MyTech(dls.SS):
         name = "MY_CUSTOM"
-    with pytest.warns(DeprecationWarning, match="legacy keyword API"):
-        r = simulator.simulate(np.ones(8), MyTech(8, 2),
-                               faults.baseline(2))
+    r = api.simulate(api.RunSpec(cluster=scenarios.baseline(2)),
+                     np.ones(8), technique=MyTech(8, 2))
     assert not r.hang and r.n_finished == 8
     assert r.technique == "MY_CUSTOM"
 
@@ -332,23 +349,16 @@ def test_snapshot_carries_rdlb_switch():
     tt = np.ones(16)
     tech = dls.make_technique("SS", 16, 2)
     queue = rdlb.RobustQueue(16, tech, rdlb_enabled=False)
-    eng = engine.Engine(queue, simulator.workers_from_scenario(
-        faults.baseline(2)), simulator.SimBackend(tt))
+    eng = engine.Engine(queue, scenarios.baseline(2).engine_workers(),
+                        simulator.SimBackend(tt))
     snap = capture(eng, 0.0)
     assert snap.rdlb_enabled is False
     assert not base_spec_from_snapshot(snap).robustness.rdlb_enabled
 
 
-def test_simulate_rejects_spec_plus_legacy():
-    tt = np.ones(8)
-    spec = api.RunSpec(cluster=api.ClusterSpec(n_workers=2))
-    with pytest.raises(TypeError):
-        simulator.simulate(tt, spec=spec, rdlb_enabled=False)
-    with pytest.raises(TypeError):
-        simulator.simulate(tt)
-
-
 def test_executor_ctor_legacy_warns():
+    """Both executors are configured by a RunSpec alone: the old
+    keywords, and a missing ``spec=``, raise TypeError."""
     pytest.importorskip("jax")
     from repro.models import build_model
     from repro.models.config import ModelConfig
@@ -356,33 +366,34 @@ def test_executor_ctor_legacy_warns():
     cfg = ModelConfig(family="dense", n_layers=1, d_model=32, n_heads=2,
                       n_kv_heads=2, d_ff=64, vocab_size=64)
     model = build_model(cfg)
-    with pytest.warns(DeprecationWarning, match="legacy keyword API"):
-        ex = RDLBTrainExecutor(model, n_workers=2, n_tasks=4,
-                               technique="GSS", rdlb_enabled=False)
-    assert ex.spec.scheduling.technique == "GSS"
-    assert not ex.spec.robustness.rdlb_enabled
-    assert ex.spec.cluster.n_workers == 2 and ex.spec.n_tasks == 4
-    with pytest.raises(TypeError):
-        RDLBTrainExecutor(model, spec=ex.spec, technique="SS")
     params = model.init(__import__("jax").random.PRNGKey(0))
-    with pytest.warns(DeprecationWarning, match="legacy keyword API"):
-        sx = RDLBServeExecutor(model, params, n_workers=3,
-                               technique="GSS")
-    assert sx.spec.cluster.n_workers == 3
-    # spec path emits no deprecation warning
+    train = api.train_spec(technique="GSS", n_workers=2, n_tasks=4)
+    serve = api.serve_spec(technique="GSS", n_workers=3)
+    old = (dict(n_workers=2), dict(n_tasks=4), dict(technique="SS"),
+           dict(rdlb_enabled=False), dict(max_duplicates=2),
+           dict(concurrent=True))
+    for kw in old:
+        with pytest.raises(TypeError):
+            RDLBTrainExecutor(model, spec=train, **kw)
+        with pytest.raises(TypeError):
+            RDLBServeExecutor(model, params, spec=serve, **kw)
+    with pytest.raises(TypeError):
+        RDLBTrainExecutor(model)
+    with pytest.raises(TypeError):
+        RDLBServeExecutor(model, params)
+    # the spec path builds cleanly, with no warning
     with warnings.catch_warnings():
         warnings.simplefilter("error", DeprecationWarning)
-        RDLBServeExecutor(model, params, spec=sx.spec)
-        RDLBTrainExecutor(model, spec=ex.spec)
+        assert RDLBTrainExecutor(model, spec=train).spec is train
+        assert RDLBServeExecutor(model, params, spec=serve).spec is serve
 
 
 # ------------------------------------------------------ adaptive via spec
 def test_spec_enables_adaptive_controller():
     tt = np.full(256, 0.01)
-    sc = faults.pe_perturbation(8, node_size=4)
     spec = api.RunSpec(
         scheduling=api.SchedulingSpec(technique="FAC"),
-        cluster=api.ClusterSpec.from_scenario(sc),
+        cluster=scenarios.pe_perturbation(8, node_size=4),
         adaptive=api.AdaptiveSpec(
             enabled=True, decision_every_chunks=16, min_remaining=16,
             max_sim_tasks=None,

@@ -90,13 +90,14 @@ def test_checkpoint_async_overlap(tmp_path):
 def test_restart_training_equivalence(tmp_path):
     """checkpoint -> restart reproduces the same parameters as an
     uninterrupted run (the checkpoint/restart baseline of §3.1)."""
+    from repro import api
     from repro.models import build_model
     from repro.runtime import RDLBTrainExecutor
     cfg = ModelConfig(family="dense", n_layers=1, d_model=32, n_heads=2,
                       n_kv_heads=2, d_ff=64, vocab_size=128)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    ex = RDLBTrainExecutor(model, n_workers=2, n_tasks=4,
+    ex = RDLBTrainExecutor(model, spec=api.train_spec(n_workers=2, n_tasks=4),
                            exact_accumulation=True)
     opt = ex.opt.init(params)
 

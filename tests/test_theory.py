@@ -5,7 +5,9 @@ import math
 import numpy as np
 import pytest
 
-from repro.core import faults, simulator, theory
+from repro import api
+from repro.api import scenarios
+from repro.core import theory
 
 
 def test_no_failure_T():
@@ -54,10 +56,12 @@ def test_simulator_single_failure_within_theory_envelope():
     q-1 survivors)."""
     q, n, t = 8, 64, 0.01
     T = n * t
+    spec = api.RunSpec(scheduling=api.SchedulingSpec(technique="SS"),
+                       execution=api.ExecutionSpec(h=1e-7))
     extras = []
     for seed in range(30):
-        sc = faults.failures(q, 1, t_exec_estimate=T, seed=seed)
-        r = simulator.run(np.full(q * n, t), "SS", sc, h=1e-7)
+        sc = scenarios.failures(q, 1, t_exec_estimate=T, seed=seed)
+        r = api.simulate(spec.replace(cluster=sc), np.full(q * n, t))
         assert not r.hang
         extras.append(r.t_par - T)
     worst = (n + 1) * t / 2 * (q / (q - 1)) + n * t * 0.2
