@@ -53,21 +53,31 @@ def task_times(n_tasks: int = PAPER_N, *, cloud_n: int = CLOUD,
     return base * (1.0 + jitter * rng.standard_normal(n_tasks)).clip(0.5)
 
 
-@functools.partial(jax.jit, static_argnames=("n_alpha", "n_beta"))
-def _spin_images(pts, ctr, nrm, ids, *, n_alpha: int, n_beta: int):
+@functools.partial(jax.jit, static_argnames=("n", "n_alpha", "n_beta"))
+def _spin_images(pts, ctr, nrm, start, *, n: int, n_alpha: int,
+                 n_beta: int):
+    """Spin images of the ``n`` oriented points from ``start`` on: the
+    start is traced, so every run of one length runs one program."""
+    ctr, nrm = (jax.lax.dynamic_slice_in_dim(t, start, n)
+                for t in (ctr, nrm))
     return spin_image_kernel(
-        pts, ctr[ids], nrm[ids], n_alpha=n_alpha, n_beta=n_beta,
+        pts, ctr, nrm, n_alpha=n_alpha, n_beta=n_beta,
         alpha_max=3.0, beta_max=3.0, block_p=BLOCK_P)
 
 
 def compute_tasks(task_ids, *, n: int = PAPER_N, cloud_n: int = CLOUD,
                   n_alpha: int = N_ALPHA, n_beta: int = N_BETA
                   ) -> np.ndarray:
-    """Compute spin images for a chunk of oriented points (runtime tasks)
-    as one device program: gather and kernel compile once per chunk
-    size."""
-    def dispatch():
+    """Compute spin images for oriented points ``task_ids`` (runtime
+    tasks), in their order.  Each maximal run of consecutive ids is one
+    device program, sent as its start and sliced on the device; the
+    kernel compiles once per run length.  An engine chunk is one run."""
+    ids = np.asarray(task_ids)
+    runs = np.split(ids, np.flatnonzero(np.diff(ids) != 1) + 1)
+
+    def dispatch(run: np.ndarray):
         ctr, nrm = oriented_points(n)
-        return _spin_images(cloud(cloud_n), ctr, nrm, jnp.asarray(task_ids),
-                            n_alpha=n_alpha, n_beta=n_beta)
-    return chunk_to_host(dispatch)
+        return _spin_images(cloud(cloud_n), ctr, nrm, np.int32(run[0]),
+                            n=run.size, n_alpha=n_alpha, n_beta=n_beta)
+    rows = [chunk_to_host(functools.partial(dispatch, run)) for run in runs]
+    return rows[0] if len(rows) == 1 else np.concatenate(rows)

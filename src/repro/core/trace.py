@@ -62,8 +62,8 @@ name:
   * ``engine.commit`` — ``backend.commit``, after the commit lock is
     released: each call gets ids no other report won, so several
     workers' commits may be open at once;
-  * ``chunk.dispatch`` — a chunk entry's input lookups, the ids or the
-    chunk's start sent to the device and the jitted call's return;
+  * ``chunk.dispatch`` — a chunk entry's input lookups, the chunk's
+    start sent to the device and the jitted call's return;
   * ``chunk.to_host`` — ``np.asarray`` of the chunk's result: the wait
     for the device and the copy to the host, one blocking call.
 

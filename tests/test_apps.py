@@ -82,6 +82,61 @@ def test_psia_chunk_recompute_identical():
     assert a.shape == (3, psia.N_BETA, psia.N_ALPHA)
 
 
+PSIA_N, PSIA_CLOUD = 64, 512
+
+
+@pytest.mark.parametrize("ids", [
+    [0], list(range(20, 27)), list(range(3, 16)), list(range(51, 64)),
+    [3, 5, 7], [7, 3], [5, 5]],
+    ids=["first", "size7", "across8", "last", "gaps", "unsorted",
+         "repeated"])
+def test_psia_chunks_match_the_gather(ids):
+    """Each run of consecutive ids, sliced on the device from its start,
+    gives the spin images of the kernel on the gathered rows, bit for
+    bit and in the order of the ids."""
+    from repro.kernels.ops import spin_image
+    ctr, nrm = psia.oriented_points(PSIA_N)
+    take = np.asarray(ids)
+    want = jax.jit(lambda pts, c, n: spin_image(
+        pts, c, n, n_alpha=psia.N_ALPHA, n_beta=psia.N_BETA,
+        alpha_max=3.0, beta_max=3.0, block_p=psia.BLOCK_P))(
+        psia.cloud(PSIA_CLOUD), ctr[take], nrm[take])
+    got = psia.compute_tasks(ids, n=PSIA_N, cloud_n=PSIA_CLOUD)
+    assert got.shape == (len(ids), psia.N_BETA, psia.N_ALPHA)
+    np.testing.assert_array_equal(got, np.asarray(want))
+
+
+def test_psia_chunk_opens_the_chunk_spans(monkeypatch):
+    """A chunk is one run, so one pair of the chunk spans; ids with gaps
+    open one pair per run."""
+    from repro.core import trace as trc
+    opened = []
+    span = trc.span
+
+    @contextlib.contextmanager
+    def spy(name):
+        opened.append(name)
+        with span(name):
+            yield
+    monkeypatch.setattr(trc, "span", spy)
+    psia.compute_tasks(np.arange(3, 10), n=PSIA_N, cloud_n=PSIA_CLOUD)
+    assert opened == ["chunk.dispatch", "chunk.to_host"]
+    opened.clear()
+    psia.compute_tasks([3, 5, 7], n=PSIA_N, cloud_n=PSIA_CLOUD)
+    assert opened == ["chunk.dispatch", "chunk.to_host"] * 3
+
+
+def test_psia_chunk_start_is_traced():
+    """Two chunks of one size at different starts compile one program."""
+    jax.block_until_ready((psia.cloud(PSIA_CLOUD),
+                           psia.oriented_points(PSIA_N)))
+    with _compiles() as seen:
+        for start in (5, 40):           # a size no other test compiles
+            psia.compute_tasks(np.arange(start, start + 11), n=PSIA_N,
+                               cloud_n=PSIA_CLOUD)
+    assert len(seen) == 1
+
+
 def test_mandelbrot_task_times_high_variance():
     tt = mandelbrot.task_times(1024, side=64, max_iters=128)
     assert tt.std() / tt.mean() > 0.5
